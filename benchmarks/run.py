@@ -155,7 +155,7 @@ def kernel_microbench(tiny: bool = False):
         kops.set_backend("ref")
         t_ref = timed(jax.jit(lambda q: kops.paged_decode_attn(q, layer, ptj, lens)), qd)
         rows.append(("kernel/paged_decode_attn_ref", t_ref, 0.0))
-        kops.set_backend("pallas")
+        kops.set_backend("pallas_interpret")
         t_pal = timed(lambda q: kops.paged_decode_attn(q, layer, ptj, lens), qd)
         rows.append(("kernel/paged_decode_attn_pallas_interp", t_pal, 0.0))
     finally:
@@ -929,7 +929,10 @@ def sharded_serving_bench(tiny: bool = False):
     with tempfile.NamedTemporaryFile("w", suffix=".py", delete=False) as f:
         f.write(_SHARDED_SCRIPT)
         script = f.name
-    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    # a simulated-CPU-mesh measurement by design: the child never touches
+    # an accelerator, which on a TPU host stays with this (parent) process
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               JAX_PLATFORMS="cpu")
     if tiny:
         env["REPRO_BENCH_TINY"] = "1"
     print("\n== sharded serving bench (2 simulated devices, CPU) ==")
@@ -973,6 +976,9 @@ def main() -> None:
                     help="skip the (slow) trained-model paper tables")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import paper_tables as pt
     from .roofline_table import roofline_table
 

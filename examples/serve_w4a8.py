@@ -247,6 +247,9 @@ def main():
                          "and the drain prints per-shard page residency")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.families:
         serve_families(None if args.backend == "ref" else args.backend)
         return
@@ -267,7 +270,8 @@ def main():
 
     rng = np.random.default_rng(0)
     # 'pallas' routes every PackedLinear matmul through the fused single-pass
-    # W4A8 kernel (compiled on TPU, interpreter elsewhere)
+    # W4A8 kernel, compiled for the TPU; 'pallas_interpret' runs the same
+    # kernels under the Pallas interpreter on any platform
     kv_fmt = None if args.kv_fmt == "bf16" else args.kv_fmt
     frozen_fmt = None if args.frozen_kv_fmt == "none" else args.frozen_kv_fmt
     if frozen_fmt and not args.shared_prefix:

@@ -17,7 +17,6 @@ inside VMEM).
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -39,14 +38,12 @@ def _kernel(x_ref, q_ref, s_ref, *, fmt):
 
 @functools.partial(jax.jit, static_argnames=("fmt_name", "block_rows", "interpret"))
 def act_quant_pallas(x, fmt_name: str = "fp8_e4m3", block_rows: int = 8,
-                     interpret: Optional[bool] = None):
+                     interpret: bool = False):
     """x: (..., d) -> (values_on_grid f32, scale (..., 1) f32).
 
     Semantics identical to kernels.ref.act_quant_ref (asserted by the
-    sweep tests). ``interpret=None`` resolves from the runtime: compiled on
-    TPU, interpreter elsewhere (kernels.ops.interpret_mode)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    sweep tests). Compiled for the TPU unless ``interpret=True`` asks for
+    the Pallas interpreter."""
     fmt = FORMATS[fmt_name]
     lead = x.shape[:-1]
     d = x.shape[-1]

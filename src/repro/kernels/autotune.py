@@ -160,8 +160,7 @@ def autotune_gemm(
         m, n = dims
         seen = set()
         candidates = [c for c in
-                      ((clamp_block(m, bm), clamp_block(n, bn))
-                       for bm, bn in candidates)
+                      (clamp_block(m, n, bm, bn) for bm, bn in candidates)
                       if not (c in seen or seen.add(c))]
     # build + warm every runnable candidate first, then time them in
     # interleaved rounds taking per-candidate minima — sequential

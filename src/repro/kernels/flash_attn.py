@@ -91,7 +91,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, causal, block_q, block_k, t):
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention_pallas(q, k, v, causal: bool = True, block_q: int = 128,
-                           block_k: int = 128, interpret: bool = True):
+                           block_k: int = 128, interpret: bool = False):
     """q: (B, S, H, hd); k/v: (B, T, H, hd|dv). Returns (B, S, H, dv)."""
     b, s, h, hd = q.shape
     t = k.shape[1]

@@ -1,13 +1,13 @@
 """jit'd wrappers over the Pallas kernels with jnp-reference fallback.
 
 Backend selection:
-  * 'ref'      — pure-jnp oracle semantics (default off-TPU; also what the
+  * 'ref'      — pure-jnp oracle semantics (the default; also what the
                  dry-run lowers, so rooflines see realistic HLO).
-  * 'pallas'   — pl.pallas_call TPU kernels, compiled on real TPU. Off-TPU
-                 the kernels transparently run in interpreter mode (there is
-                 no hardware to compile for), so 'pallas' is always safe to
-                 select.
-  * 'pallas_interpret' — force interpreter mode even on TPU (debugging).
+  * 'pallas'   — pl.pallas_call TPU kernels, always compiled. Selecting it
+                 where JAX's platform is not 'tpu' raises: the interpreter
+                 never stands in for the chip unasked.
+  * 'pallas_interpret' — the same kernels under the Pallas interpreter
+                 (CPU tests, debugging on any platform).
 Set via set_backend() or REPRO_KERNEL_BACKEND env var.
 
 Under the pallas backend the hot path is the *fused single-pass* kernel
@@ -29,15 +29,18 @@ from . import ref as _ref
 
 _BACKEND = os.environ.get("REPRO_KERNEL_BACKEND", "ref")
 
-# Mesh the paged decode-attention wrappers shard over (None = single-device,
-# today's exact dataflow). Scoped by the serving engine around every trace —
-# a module global like _BACKEND, read at trace time.
-_DECODE_MESH = [None]
+# Serving mesh the kernel wrappers shard_map over — paged decode attention
+# by head, the fused GEMM column-parallel (None = single-device). Scoped by
+# the serving engine around every trace — a module global like _BACKEND,
+# read at trace time.
+_SERVING_MESH = [None]
 
 
 def set_backend(name: str):
     global _BACKEND
     assert name in ("ref", "pallas", "pallas_interpret")
+    if name == "pallas":
+        _require_tpu()
     _BACKEND = name
 
 
@@ -45,22 +48,36 @@ def get_backend() -> str:
     return _BACKEND
 
 
-def set_decode_mesh(mesh):
-    """Install (or clear, with None) the mesh the paged decode-attention
-    entry points shard_map over. Head-sharded decode is only taken when the
-    KV-head dim divides the 'model' axis; otherwise the call falls through
-    to the unsharded dataflow and GSPMD handles placement."""
-    _DECODE_MESH[0] = mesh
+def set_serving_mesh(mesh):
+    """Install (or clear, with None) the mesh the kernel entry points
+    shard_map over. Head-sharded decode is only taken when the KV-head dim
+    divides the 'model' axis; otherwise the call falls through to the
+    unsharded dataflow and GSPMD handles placement. The 2-D fused GEMM
+    always runs column-parallel under shard_map on a mesh, and raises
+    where its output rows do not split into whole lane tiles."""
+    _SERVING_MESH[0] = mesh
 
 
-def get_decode_mesh():
-    return _DECODE_MESH[0]
+def get_serving_mesh():
+    return _SERVING_MESH[0]
+
+
+def _require_tpu():
+    platform = jax.default_backend()
+    if platform != "tpu":
+        raise RuntimeError(
+            f"kernel backend 'pallas' compiles for the TPU, but JAX's "
+            f"platform is {platform!r}; select 'pallas_interpret' to run "
+            "the kernels under the Pallas interpreter")
 
 
 def interpret_mode() -> bool:
-    """True when pallas kernels must run under the interpreter: either the
-    explicit 'pallas_interpret' backend, or no TPU to compile for."""
-    return _BACKEND == "pallas_interpret" or jax.default_backend() != "tpu"
+    """True when pallas kernels run under the interpreter — only when the
+    'pallas_interpret' backend asks for it. 'pallas' (also when set via
+    REPRO_KERNEL_BACKEND) is checked here to be on a TPU."""
+    if _BACKEND == "pallas":
+        _require_tpu()
+    return _BACKEND == "pallas_interpret"
 
 
 def _block_sizes(kind: str, w, m: int, n: int, k: int, batch: int = 1,
@@ -96,16 +113,44 @@ def w4a8_matmul(x, w):
         lead = x.shape[:-1]
         k = x.shape[-1]
         x2 = x.reshape(-1, k)
-        bm, bn = _block_sizes("fused", w, x2.shape[0], w.out_features, k)
-        y = w4a8_fused_matmul_pallas(
-            x2, w.codes, w.scale, w.s_max, w.shifts, w.lorc_a, w.lorc_b,
-            w_fmt=w.w_fmt, a_fmt=w.a_fmt, group_size=w.group_size,
-            bm=bm, bn=bn, interpret=interpret_mode(),
-        )
+        fields = (w.codes, w.scale, w.s_max, w.shifts, w.lorc_a, w.lorc_b)
+        mesh = _SERVING_MESH[0]
+        msize = 1 if mesh is None else mesh.shape.get("model", 1)
+        n_loc = w.out_features // msize
+        if msize > 1 and (w.out_features % msize or n_loc % 128):
+            raise ValueError(
+                f"the fused W4A8 GEMM runs column-parallel on a serving mesh "
+                f"(a Mosaic call cannot be partitioned automatically): its "
+                f"{w.out_features} output rows do not split into 'model'="
+                f"{msize} shards of whole 128-lane tiles")
+        bm, bn = _block_sizes("fused", w, x2.shape[0], n_loc, k)
+
+        def gemm(xs, *f):
+            return w4a8_fused_matmul_pallas(
+                xs, *f, w_fmt=w.w_fmt, a_fmt=w.a_fmt,
+                group_size=w.group_size, bm=bm, bn=bn,
+                interpret=interpret_mode())
+
+        if msize > 1:
+            from jax.sharding import PartitionSpec as P
+
+            # each 'model' shard owns N/m weight rows; LoRC's B factor
+            # (rank x K) and the activations replicate
+            row = P("model", None)
+            specs = tuple(None if f is None else
+                          (P() if name == "lorc_b" else row)
+                          for name, f in zip(
+                              ("codes", "scale", "s_max", "shifts",
+                               "lorc_a", "lorc_b"), fields))
+            gemm = jax.shard_map(
+                gemm, mesh=mesh, in_specs=(P(),) + specs,
+                out_specs=P(None, "model"), check_vma=False)
+        y = gemm(x2, *fields)
         return y.reshape(*lead, -1).astype(x.dtype)
     return _ref.w4a8_matmul_ref(
         x, w.codes, w.scale, w.lorc_a, w.lorc_b,
         w_fmt=w.w_fmt, a_fmt=w.a_fmt, group_size=w.group_size,
+        s_max=w.s_max, shifts=w.shifts,
     )
 
 
@@ -135,7 +180,7 @@ def w4a8_matmul_batched(x, w, transpose_w: bool = False,
     return _ref.w4a8_batched_matmul_ref(
         x, w.codes, w.scale, w.lorc_a, w.lorc_b,
         w_fmt=w.w_fmt, a_fmt=a_fmt, group_size=w.group_size,
-        transpose_w=transpose_w,
+        transpose_w=transpose_w, s_max=w.s_max, shifts=w.shifts,
     )
 
 
@@ -193,29 +238,28 @@ def paged_decode_attn(q, pool_layer, page_table, kv_lens, window: int = 0):
     kv_lens: (B,) int32 valid token counts; ``window``: sliding-window size
     (0 = full history). Returns (B, H, dv) f32.
 
-    With a decode mesh installed (``set_decode_mesh``) and the KV-head dim
+    With a serving mesh installed (``set_serving_mesh``) and the KV-head dim
     divisible by the 'model' axis, the whole dataflow runs under
     ``shard_map`` with pages/scales head-sharded: each shard attends its
     own KV-head group against its slice of every page — queries arrive
     head-sharded, no collectives, outputs stay head-sharded. Non-divisible
     head counts fall through to the unsharded call (GSPMD places it).
     """
-    mesh = _DECODE_MESH[0]
+    mesh = _SERVING_MESH[0]
     if mesh is not None:
         msize = mesh.shape.get("model", 1)
         kvh, h = pool_layer["k"].shape[2], q.shape[1]
         if msize > 1 and kvh % msize == 0 and h % msize == 0:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             specs = {n: _pool_shard_spec(n, l, msize)
                      for n, l in pool_layer.items()}
             hspec = P(None, "model", None)
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda qv, pl, pt, kl: _paged_decode_attn_impl(
                     qv, pl, pt, kl, window=window),
                 mesh=mesh, in_specs=(hspec, specs, P(), P()),
-                out_specs=hspec, check_rep=False)
+                out_specs=hspec, check_vma=False)
             return fn(q, pool_layer, page_table, kv_lens)
     return _paged_decode_attn_impl(q, pool_layer, page_table, kv_lens,
                                    window=window)
@@ -236,19 +280,11 @@ def _paged_decode_attn_impl(q, pool_layer, page_table, kv_lens,
         ksh = vsh = jnp.zeros((1, 1), jnp.int32)
     fz = _fz_operands(pool_layer, ("k", "v"))
     if _BACKEND.startswith("pallas"):
-        from .autotune import best_block_sizes
         from .decode_attn import paged_decode_attn_pallas
 
-        b, h, hd = q.shape
-        page, kv = kp.shape[1], kp.shape[2]
-        bq, _ = best_block_sizes(
-            "decode_attn", batch=b, m=h // kv, n=page, k=hd,
-            w_fmt=fmt.name or "bf16", a_fmt=None, group_size=page, m2=True,
-            lorc_rank=0,
-        )
         return paged_decode_attn_pallas(
             q, kp, vp, ksm, ksh, vsm, vsh, page_table, kv_lens,
-            fmt=fmt, frozen=frozen, bq=bq, window=window,
+            fmt=fmt, frozen=frozen, window=window,
             interpret=interpret_mode(), **fz,
         )
     return _ref.paged_decode_attn_ref(
@@ -272,25 +308,24 @@ def paged_mla_decode_attn(q_lat, q_rope, pool_layer, page_table, kv_lens,
     the scalar-prefetched page table and dequantizes FP8 in VMEM. Ref: the
     gathered-page jnp oracle.
 
-    With a decode mesh installed, the absorbed query heads shard along
+    With a serving mesh installed, the absorbed query heads shard along
     'model' while the latent pool (no head axis) replicates — each shard
     runs its head group against the full latent pages, so the contraction
     is local and the (B, H, r) context comes back head-sharded.
     """
-    mesh = _DECODE_MESH[0]
+    mesh = _SERVING_MESH[0]
     if mesh is not None:
         msize = mesh.shape.get("model", 1)
         if msize > 1 and q_lat.shape[1] % msize == 0:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             specs = {n: P() for n in pool_layer}  # latents: no head axis
             hspec = P(None, "model", None)
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda ql, qr, pl, pt, kl: _paged_mla_decode_attn_impl(
                     ql, qr, pl, pt, kl, scale=scale),
                 mesh=mesh, in_specs=(hspec, hspec, specs, P(), P()),
-                out_specs=hspec, check_rep=False)
+                out_specs=hspec, check_vma=False)
             return fn(q_lat, q_rope, pool_layer, page_table, kv_lens)
     return _paged_mla_decode_attn_impl(q_lat, q_rope, pool_layer, page_table,
                                        kv_lens, scale=scale)

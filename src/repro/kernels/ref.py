@@ -24,11 +24,20 @@ def act_quant_ref(x, fmt_name: str = "fp8_e4m3"):
     return quantize_act_tokenwise(x, fmt_name)
 
 
+def _bf16(x):
+    """Round to bf16 and keep the rounding: an f32 -> bf16 -> f32 convert
+    pair may be elided inside a larger XLA program (excess precision),
+    ``reduce_precision`` may not — the kernels round in VMEM."""
+    x = jax.lax.reduce_precision(x.astype(jnp.float32), exponent_bits=8,
+                                 mantissa_bits=7)
+    return x.astype(jnp.bfloat16)
+
+
 def dequant_packed_ref(codes, scale, fmt_name: str = "fp4_e2m1", group_size: int = 256):
     """codes: (..., out, in/2) packed nibbles; scale: (..., out, n_groups).
     Returns (..., out, in) BF16 dequantized weights — bf16 is what the TPU
-    kernel materializes in VMEM (decode product is exact in bf16 for E2M1's
-    1-mantissa-bit grid times a pow-2-constrained scale)."""
+    kernel materializes in VMEM (exact for E2M1's 1-mantissa-bit grid times
+    a power of two; a general scale rounds)."""
     fmt = FORMATS[fmt_name]
     q = fp_decode(unpack_nibbles(codes), fmt)  # (..., out, in) f32
     out_f, in_f = q.shape[-2], q.shape[-1]
@@ -36,32 +45,48 @@ def dequant_packed_ref(codes, scale, fmt_name: str = "fp4_e2m1", group_size: int
     gs = in_f // n_groups
     qg = q.reshape(*q.shape[:-1], n_groups, gs)
     w = (qg * scale[..., None].astype(jnp.float32)).reshape(*q.shape[:-2], out_f, in_f)
-    return w.astype(jnp.bfloat16)
+    return _bf16(w)
+
+
+def _act_operand(x, a_fmt):
+    """The bf16 activation operand: token-wise FP8 grid values x scale. A
+    bf16 ``x`` is pinned to bf16 first: the kernel reads it from memory,
+    where a fused producer's f32 intermediate never reaches it."""
+    if x.dtype == jnp.bfloat16 or not a_fmt:
+        x = _bf16(x)
+    if not a_fmt:
+        return x
+    qx, sx = quantize_act_tokenwise(x, a_fmt)
+    return _bf16(qx * sx)
 
 
 def w4a8_matmul_ref(x, codes, scale, lorc_a=None, lorc_b=None,
                     w_fmt: str = "fp4_e2m1", a_fmt: str = "fp8_e4m3",
-                    group_size: int = 256):
+                    group_size: int = 256, s_max=None, shifts=None):
     """The W4A8 GEMM semantics: token-wise-FP8 activations x packed-FP4
     weights (+ optional LoRC low-rank side path).
 
     x: (..., in) float; codes: (out, in/2) uint8; scale: (out, G) f32.
-    Returns (..., out) in x.dtype.
+    With the M2 decomposition (s_max (out, 1), shifts (out, G)) the weight
+    operand is the exact bf16 ``q * 2^-shift`` and ``s_max`` scales the f32
+    result, as the fused kernel applies it. Returns (..., out) in x.dtype.
     """
     from repro.models.layers import accum_dtype
 
-    if a_fmt:
-        qx, sx = quantize_act_tokenwise(x, a_fmt)
-        xq = (qx * sx).astype(jnp.bfloat16)  # values on grid * scale
+    xq = _act_operand(x, a_fmt)
+    if shifts is not None:
+        w = dequant_packed_ref(codes, pow2i(-shifts.astype(jnp.int32)),
+                               w_fmt, group_size)
     else:
-        xq = x.astype(jnp.bfloat16)
-    w = dequant_packed_ref(codes, scale, w_fmt, group_size)  # (out, in) bf16
+        w = dequant_packed_ref(codes, scale, w_fmt, group_size)
     y = jax.lax.dot_general(xq, w, (((xq.ndim - 1,), (1,)), ((), ())),
                             preferred_element_type=accum_dtype())
+    if shifts is not None:
+        y = y * s_max.reshape(-1).astype(y.dtype)
     if lorc_a is not None:
         y = y + jax.lax.dot_general(
-            jax.lax.dot_general(xq, lorc_b, (((xq.ndim - 1,), (1,)), ((), ())),
-                                preferred_element_type=accum_dtype()).astype(jnp.bfloat16),
+            _bf16(jax.lax.dot_general(xq, lorc_b, (((xq.ndim - 1,), (1,)), ((), ())),
+                                      preferred_element_type=accum_dtype())),
             lorc_a, (((xq.ndim - 1,), (1,)), ((), ())),
             preferred_element_type=accum_dtype()).astype(y.dtype)
     return y.astype(x.dtype)
@@ -69,33 +94,38 @@ def w4a8_matmul_ref(x, codes, scale, lorc_a=None, lorc_b=None,
 
 def w4a8_batched_matmul_ref(x, codes, scale, lorc_a=None, lorc_b=None,
                             w_fmt: str = "fp4_e2m1", a_fmt=None,
-                            group_size: int = 256, transpose_w: bool = False):
+                            group_size: int = 256, transpose_w: bool = False,
+                            s_max=None, shifts=None):
     """Oracle for the batched fused kernel (MoE expert stacks, MLA absorbed
     heads). x: (E, M, D); codes: (E, N, In/2); scale: (E, N, G).
 
     normal: D == In, y[e] = x[e] @ W[e]^T -> (E, M, N);
     transposed: D == N, y[e] = x[e] @ W[e] -> (E, M, In) (the MLA absorbed q
     path contracts the packed weight's out rows).
-    LoRC is the same low-rank *side path* as the fused epilogue. Returns f32.
+    LoRC is the same low-rank *side path* as the fused epilogue. With the M2
+    decomposition (s_max (E, N, 1), shifts (E, N, G)) the normal
+    orientation scales the f32 result by s_max, as the kernel does; the
+    transposed one folds the whole scale into the bf16 weight. Returns f32.
     """
-    if a_fmt:
-        qx, sx = quantize_act_tokenwise(x, a_fmt)
-        xq = (qx * sx).astype(jnp.bfloat16)
-    else:
-        xq = x.astype(jnp.bfloat16)
+    xq = _act_operand(x, a_fmt)
+    post = shifts is not None and not transpose_w
+    if post:
+        scale = pow2i(-shifts.astype(jnp.int32))
     w = dequant_packed_ref(codes, scale, w_fmt, group_size)  # (E, N, In) bf16
     if transpose_w:
         y = jnp.einsum("emn,eni->emi", xq, w, preferred_element_type=jnp.float32)
         if lorc_a is not None:
-            xr = jnp.einsum("emn,enr->emr", xq, lorc_a.astype(jnp.bfloat16),
-                            preferred_element_type=jnp.float32).astype(jnp.bfloat16)
+            xr = _bf16(jnp.einsum("emn,enr->emr", xq, lorc_a.astype(jnp.bfloat16),
+                                  preferred_element_type=jnp.float32))
             y = y + jnp.einsum("emr,eri->emi", xr, lorc_b.astype(jnp.bfloat16),
                                preferred_element_type=jnp.float32)
     else:
         y = jnp.einsum("emk,enk->emn", xq, w, preferred_element_type=jnp.float32)
+        if post:
+            y = y * s_max.reshape(s_max.shape[0], 1, -1).astype(jnp.float32)
         if lorc_a is not None:
-            xr = jnp.einsum("emk,erk->emr", xq, lorc_b.astype(jnp.bfloat16),
-                            preferred_element_type=jnp.float32).astype(jnp.bfloat16)
+            xr = _bf16(jnp.einsum("emk,erk->emr", xq, lorc_b.astype(jnp.bfloat16),
+                                  preferred_element_type=jnp.float32))
             y = y + jnp.einsum("emr,enr->emn", xr, lorc_a.astype(jnp.bfloat16),
                                preferred_element_type=jnp.float32)
     return y

@@ -27,7 +27,6 @@ across the K steps and is written once (revisiting semantics).
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -86,7 +85,7 @@ def w4a8_matmul_pallas(
     group_size: int = 256,
     bm: int = 128,
     bn: int = 128,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ):
     """y[m, n] = sum_k x_q[m, k] * dequant(codes, scale)[n, k].
 
@@ -94,11 +93,9 @@ def w4a8_matmul_pallas(
     codes: (N, K/2) uint8; scale: (N, G) f32; optional M2 (s_max, shifts).
     Returns (M, N) f32. Shapes must tile: M % bm == 0 is relaxed by clamping
     bm to a divisor; K % group_size == 0 required (FGQ invariant).
-    ``interpret=None`` resolves from the runtime: compiled on TPU,
-    interpreter elsewhere.
+    Compiled for the TPU unless ``interpret=True`` asks for the Pallas
+    interpreter.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     m, k = x_q.shape
     n = codes.shape[0]
     bk = group_size

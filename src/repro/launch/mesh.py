@@ -11,21 +11,16 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_mesh", "make_production_mesh", "dp_axes", "axis_size",
-           "MESH_AXES"]
+__all__ = ["make_mesh", "make_production_mesh", "dp_axes", "MESH_AXES"]
 
 MESH_AXES = {"single": ("data", "model"), "multi": ("pod", "data", "model")}
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the installed jax has them
-    (>= 0.5); on older jax (0.4.x) axis types don't exist and every axis is
-    implicitly Auto, so the plain call is equivalent. All mesh construction
-    (tests included) goes through here so the repo runs on both pins."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """jax.make_mesh with every axis Auto (GSPMD-propagated shardings), the
+    axis type all of the repo's sharding rules assume."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -38,13 +33,3 @@ def dp_axes(mesh) -> tuple:
     """Axes that carry the batch (pod composes with data)."""
     return tuple(a for a in ("pod", "data") if a in mesh.shape)
 
-
-def axis_size(name):
-    """Mapped-axis size inside shard_map bodies. jax >= 0.5 has
-    lax.axis_size; the 0.4.x spelling is psum(1, axis), folded to a static
-    int at trace time. The compat shim lives here with make_mesh so a jax
-    pin bump touches one module."""
-    size = getattr(jax.lax, "axis_size", None)
-    if size is not None:
-        return size(name)
-    return jax.lax.psum(1, name)

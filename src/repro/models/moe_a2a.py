@@ -30,7 +30,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .layers import activation, batched_linear, linear, mlp, quant_act
@@ -81,11 +80,9 @@ def _a2a2(x, axes):
     if len(axes) == 1:
         return jax.lax.all_to_all(x, axes[0], split_axis=0, concat_axis=0,
                                   tiled=True)
-    from repro.launch.mesh import axis_size
-
     a, b = axes
-    na = axis_size(a)
-    nb = axis_size(b)
+    na = jax.lax.axis_size(a)
+    nb = jax.lax.axis_size(b)
     p, c, d = x.shape
     # (na, nb, C, d): exchange the inner axis first, then the outer
     x = x.reshape(na, nb * c, d)
@@ -197,10 +194,10 @@ def moe_decode_ep(p, x, cfg, mesh, a_fmt: Optional[str] = None,
 
     espec = jax.tree.map(
         lambda l: P(axes, *([None] * (l.ndim - 1))), stacked)
-    eo = shard_map(ffn, mesh=mesh,
+    eo = jax.shard_map(ffn, mesh=mesh,
                    in_specs=(P(axes, None, None), espec),
                    out_specs=P(axes, None, None),
-                   check_rep=False)(xe, stacked)
+                   check_vma=False)(xe, stacked)
 
     ex_out = jnp.moveaxis(eo.reshape(e, g, capacity, d), 0, 1)
     out = jnp.einsum("gsec,gecd->gsd", combine, ex_out.astype(jnp.float32))
@@ -280,21 +277,21 @@ def moe_layer_a2a(p, x, cfg, mesh, a_fmt: Optional[str] = None):
 
     expert_spec = P(axes, None, None)
     if wg is not None:
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(dp_only, "model", None), P(None, None), expert_spec,
                       expert_spec, expert_spec),
             out_specs=(P(dp_only, "model", None), P()),
-            check_rep=False,
+            check_vma=False,
         )
         out, aux = fn(x, router_w, wu, wg, wd)
     else:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda xb, rw, a, c: body(xb, rw, a, None, c), mesh=mesh,
             in_specs=(P(dp_only, "model", None), P(None, None), expert_spec,
                       expert_spec),
             out_specs=(P(dp_only, "model", None), P()),
-            check_rep=False,
+            check_vma=False,
         )
         out, aux = fn(x, router_w, wu, wd)
 

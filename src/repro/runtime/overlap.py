@@ -21,7 +21,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["ring_ag_matmul"]
@@ -30,9 +29,7 @@ __all__ = ["ring_ag_matmul"]
 def _ring_body(x_blk, w, axis_name: str):
     """x_blk: (B, s_loc, d) local seq shard; w: (out_loc, d) local rows.
     Returns (B, P*s_loc, out_loc): the full-seq output for local out rows."""
-    from repro.launch.mesh import axis_size
-
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
 
     def step(carry, i):
@@ -60,11 +57,11 @@ def ring_ag_matmul(x, w, mesh, axis_name: str = "model", dp=("data",)):
     """Overlapped all-gather(x over seq) + matmul. x: (B, S, d) seq-sharded
     on ``axis_name``; w: (out, d) out-sharded on ``axis_name``.
     Returns (B, S, out) with out sharded on ``axis_name``."""
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_body, axis_name=axis_name),
         mesh=mesh,
         in_specs=(P(dp, axis_name, None), P(axis_name, None)),
         out_specs=P(dp, None, axis_name),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, w)

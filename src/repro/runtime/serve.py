@@ -126,7 +126,7 @@ __all__ = ["Request", "RequestResult", "TokenEvent", "Server",
 
 
 def _decode_step(params, caches, tokens, cache_index, poison, samp,
-                 cfg, a_fmt):
+                 cfg, a_fmt, backend):
     """The engine step, as a plain traceable function. ``_decode_step_jit``
     below is the shared single-device jit of it; a mesh-driving Server
     jits the SAME function with ``out_shardings`` pinning the cache
@@ -137,7 +137,8 @@ def _decode_step(params, caches, tokens, cache_index, poison, samp,
     ``cfg`` is a frozen (hashable) ArchConfig, so the compiled program
     cache is shared across Server instances — a restarted or side-by-side
     server reuses every prefill-chunk and decode executable instead of
-    recompiling.
+    recompiling. ``backend`` (the kernel backend the step traces under)
+    is static too: servers on different backends never share a program.
 
     Returns ``(nxt, row_ok, caches)``: ``nxt`` is the per-row next token
     — sampled in-graph from the logits by ``samp``, a 5-tuple of per-row
@@ -152,32 +153,33 @@ def _decode_step(params, caches, tokens, cache_index, poison, samp,
     is a per-row bool *input* (no retrace): fault injection sets it to
     force NaN upstream of the sentinel, so chaos tests exercise the same
     detection path production does."""
-    logits, caches = models.decode_step(params, cfg, tokens, caches,
-                                        cache_index, a_fmt=a_fmt)
+    with _backend_scope(backend):
+        logits, caches = models.decode_step(params, cfg, tokens, caches,
+                                            cache_index, a_fmt=a_fmt)
     logits = jnp.where(poison[:, None], jnp.float32(jnp.nan), logits)
     row_ok = jnp.all(jnp.isfinite(logits), axis=-1)
     nxt = smp.sample_tokens(logits, *samp)
     return nxt, row_ok, caches
 
 
-_decode_step_jit = jax.jit(_decode_step, static_argnames=("cfg", "a_fmt"))
+_STEP_STATIC = ("cfg", "a_fmt", "backend")
+_decode_step_jit = jax.jit(_decode_step, static_argnames=_STEP_STATIC)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "a_fmt"))
-def _encode_cross_jit(params, frames, caches, cross_table, cfg, a_fmt):
+@functools.partial(jax.jit, static_argnames=_STEP_STATIC)
+def _encode_cross_jit(params, frames, caches, cross_table, cfg, a_fmt,
+                      backend):
     """Enc-dec admission step: encoder forward + write-once cross pages."""
-    return models.encode_cross_pages(params, cfg, frames, caches,
-                                     cross_table, a_fmt=a_fmt)
+    with _backend_scope(backend):
+        return models.encode_cross_pages(params, cfg, frames, caches,
+                                         cross_table, a_fmt=a_fmt)
 
 
 @contextlib.contextmanager
-def _backend_scope(name: Optional[str]):
-    """Temporarily select a kernel backend (None = leave untouched). Keeps a
-    Server's backend choice scoped to its own prefill/decode tracing instead
-    of leaking into every other model in the process."""
-    if name is None:
-        yield
-        return
+def _backend_scope(name: str):
+    """Select a kernel backend for one trace. Keeps a Server's backend
+    choice scoped to its own step programs instead of leaking into every
+    other model in the process."""
     from repro.kernels import ops as _kops
 
     prev = _kops.get_backend()
@@ -957,7 +959,7 @@ class Server:
             self.pools, self._pool_shardings)
         repl = NamedSharding(mesh, P())
         self._decode = functools.partial(
-            jax.jit(_decode_step, static_argnames=("cfg", "a_fmt"),
+            jax.jit(_decode_step, static_argnames=_STEP_STATIC,
                     out_shardings=(repl, repl, self._pool_shardings)),
             cfg=cfg, a_fmt=a_fmt)
 
@@ -989,37 +991,45 @@ class Server:
                     per[key] = per.get(key, 0) + int(sh.data.nbytes)
         return dict(sorted(per.items()))
 
+    def _backend(self) -> str:
+        """The kernel backend this server's steps trace under — its own
+        choice, else the process-wide one — passed to every jitted step as
+        a static argument, so the compiled program is keyed on it."""
+        from repro.kernels import ops as _kops
+
+        return self.kernel_backend or _kops.get_backend()
+
     @contextlib.contextmanager
     def _trace_scope(self):
         """Every engine trace (encoder run, prefill chunk, decode step)
-        enters through here: the Server's kernel backend, plus — on a
-        mesh — the trace-time sharding globals: the decode-attention
-        shard_map mesh (kernels.ops), the expert-parallel MoE decode impl
-        (models.moe_a2a) and the head-sharding hint (models.layers). All
-        are restored on exit, so side-by-side servers (or a train step in
-        the same process) never see another engine's placement."""
-        with _backend_scope(self.kernel_backend):
-            if self._mesh is None:
-                yield
-                return
-            from repro.kernels import ops as _kops
-            from repro.models import layers as _layers
-            from repro.models import moe_a2a as _moe
+        enters through here. On a mesh it installs the trace-time sharding
+        globals: the kernels' shard_map mesh (kernels.ops), the
+        expert-parallel MoE decode impl (models.moe_a2a) and the
+        head-sharding hint (models.layers). All are restored on exit, so
+        side-by-side servers (or a train step in the same process) never
+        see another engine's placement. (The kernel backend is a static
+        argument of the jitted steps, see ``_backend``.)"""
+        if self._mesh is None:
+            yield
+            return
+        from repro.kernels import ops as _kops
+        from repro.models import layers as _layers
+        from repro.models import moe_a2a as _moe
 
-            prev_mesh = _kops.get_decode_mesh()
-            prev_impl = _moe.get_moe_impl()
-            prev_res = _layers._RESIDUAL_SHARDING[0]
-            prev_heads = _layers._HEADS_SHARDING[0]
-            _kops.set_decode_mesh(self._mesh)
-            if self.cfg.moe is not None:
-                _moe.set_moe_impl("ep_decode", self._mesh)
-            _layers.set_residual_sharding(prev_res, self._heads_sharding)
-            try:
-                yield
-            finally:
-                _kops.set_decode_mesh(prev_mesh)
-                _moe.set_moe_impl(*prev_impl)
-                _layers.set_residual_sharding(prev_res, prev_heads)
+        prev_mesh = _kops.get_serving_mesh()
+        prev_impl = _moe.get_moe_impl()
+        prev_res = _layers._RESIDUAL_SHARDING[0]
+        prev_heads = _layers._HEADS_SHARDING[0]
+        _kops.set_serving_mesh(self._mesh)
+        if self.cfg.moe is not None:
+            _moe.set_moe_impl("ep_decode", self._mesh)
+        _layers.set_residual_sharding(prev_res, self._heads_sharding)
+        try:
+            yield
+        finally:
+            _kops.set_serving_mesh(prev_mesh)
+            _moe.set_moe_impl(*prev_impl)
+            _layers.set_residual_sharding(prev_res, prev_heads)
 
     # -- page accounting -------------------------------------------------------
     def _worst_case_pages(self, req: Request) -> int:
@@ -1465,7 +1475,8 @@ class Server:
             with self._trace_scope():
                 self.pools = _encode_cross_jit(self.params, frames,
                                                self.pools, table,
-                                               cfg=self.cfg, a_fmt=self.a_fmt)
+                                               cfg=self.cfg, a_fmt=self.a_fmt,
+                                               backend=self._backend())
             self.stats["programs"] += 1
             self.enc_lengths[slot] = self.cfg.encoder_seq
 
@@ -1501,7 +1512,7 @@ class Server:
             with self._trace_scope():
                 nxt, row_ok, pools = self._decode(
                     self.params, self.pools, jnp.asarray([toks], jnp.int32),
-                    state, self._no_poison1, samp1)
+                    state, self._no_poison1, samp1, backend=self._backend())
             self.pools = pools
             self.stats["programs"] += 1
             ok = ok and bool(np.asarray(row_ok)[0])
@@ -2101,7 +2112,7 @@ class Server:
         with self._trace_scope():
             nxt_dev, row_ok, self.pools = self._decode(
                 self.params, self.pools, jnp.asarray(tok), state, poison,
-                smp.as_tuple(self._samp))
+                smp.as_tuple(self._samp), backend=self._backend())
         self.stats["programs"] += 1
         nxt = np.asarray(nxt_dev)
         okrow = np.asarray(row_ok)
@@ -2192,7 +2203,7 @@ class Server:
         with self._trace_scope():
             nxt_dev, row_ok, self.pools = self._decode(
                 self.params, self.pools, jnp.asarray(tok), state, poison,
-                smp.as_tuple(self._samp_m))
+                smp.as_tuple(self._samp_m), backend=self._backend())
         self.stats["programs"] += 1
         self.prefill_traces.add((padded, w))
         nxt = np.asarray(nxt_dev)

@@ -252,7 +252,7 @@ class TestFP4DecodeParity:
             ops.set_backend("ref")
             o_ref = ops.paged_decode_attn(q, layer, jnp.asarray(pt),
                                           jnp.asarray(lens))
-            ops.set_backend("pallas")
+            ops.set_backend("pallas_interpret")
             o_pal = ops.paged_decode_attn(q, layer, jnp.asarray(pt),
                                           jnp.asarray(lens))
         finally:
@@ -300,7 +300,7 @@ class TestFP4DecodeParity:
             ops.set_backend("ref")
             o_ref = ops.paged_mla_decode_attn(
                 ql, qr, layer, jnp.asarray(pt), jnp.asarray(lens), scale)
-            ops.set_backend("pallas")
+            ops.set_backend("pallas_interpret")
             o_pal = ops.paged_mla_decode_attn(
                 ql, qr, layer, jnp.asarray(pt), jnp.asarray(lens), scale)
         finally:

@@ -159,6 +159,22 @@ def test_ops_backend_switch_end_to_end():
     )
 
 
+def test_pallas_backend_refuses_non_tpu(monkeypatch):
+    """'pallas' means compiled: off a TPU it raises instead of silently
+    running the interpreter — at selection, and at dispatch when the
+    backend arrived through REPRO_KERNEL_BACKEND."""
+    assert jax.default_backend() != "tpu"
+    prev = ops.get_backend()
+    with pytest.raises(RuntimeError, match="pallas_interpret"):
+        ops.set_backend("pallas")
+    assert ops.get_backend() == prev
+    monkeypatch.setattr(ops, "_BACKEND", "pallas")
+    with pytest.raises(RuntimeError, match="pallas_interpret"):
+        ops.act_quant(jnp.ones((8, 128), jnp.float32))
+    monkeypatch.setattr(ops, "_BACKEND", "pallas_interpret")
+    assert ops.interpret_mode()
+
+
 def test_packed_codes_roundtrip_grid():
     """Every packed code decodes to a grid value (property over random w)."""
     from repro.core.formats import fp_decode, unpack_nibbles

@@ -153,7 +153,7 @@ class TestPagedDecodeAttn:
                 ops.set_backend("ref")
                 o_ref = ops.paged_decode_attn(q, layer, jnp.asarray(pt),
                                               jnp.asarray(lens))
-                ops.set_backend("pallas")
+                ops.set_backend("pallas_interpret")
                 o_pal = ops.paged_decode_attn(q, layer, jnp.asarray(pt),
                                               jnp.asarray(lens))
                 np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_ref),
@@ -184,7 +184,7 @@ class TestPagedDecodeAttn:
             ops.set_backend("ref")
             o_ref = ops.paged_decode_attn(q, layer, jnp.asarray(pt),
                                           jnp.asarray(lens), window=window)
-            ops.set_backend("pallas")
+            ops.set_backend("pallas_interpret")
             o_pal = ops.paged_decode_attn(q, layer, jnp.asarray(pt),
                                           jnp.asarray(lens), window=window)
         finally:
@@ -305,7 +305,7 @@ class TestPagedMLAKernel:
                 ops.set_backend("ref")
                 o_ref = ops.paged_mla_decode_attn(
                     ql, qr, layer, jnp.asarray(pt), jnp.asarray(lens), scale)
-                ops.set_backend("pallas")
+                ops.set_backend("pallas_interpret")
                 o_pal = ops.paged_mla_decode_attn(
                     ql, qr, layer, jnp.asarray(pt), jnp.asarray(lens), scale)
                 np.testing.assert_allclose(np.asarray(o_pal),

@@ -173,6 +173,41 @@ class TestShardedTokenIdentity:
         assert rec["ok"]
         assert rec["residency_devices"] >= 8
 
+    def test_w4a8_gemm_on_mesh(self, tmp_path):
+        """On a serving mesh the fused W4A8 GEMM runs under shard_map (a
+        TPU kernel call cannot be partitioned automatically): column-
+        parallel, bit-identical to one device where each shard's N slice
+        tiles, and refused where it does not."""
+        body = _COMMON + textwrap.dedent("""
+            import jax.numpy as jnp
+            from repro.core.policy import QuantPolicy
+            from repro.core.ptq import pack_linear
+            from repro.kernels import ops
+            from repro.launch.mesh import make_mesh
+
+            mesh = make_mesh((1, 2), ("data", "model"))
+            rng = np.random.default_rng(0)
+            pol = QuantPolicy(w_fmt="fp4_e2m1", a_fmt="fp8_e4m3",
+                              scale_mode="m2", lorc_rank=4)
+            x = jnp.asarray(rng.normal(size=(2, 5, 256)).astype(np.float32))
+            ops.set_backend("pallas_interpret")
+            packed = {n: pack_linear(jnp.asarray(
+                rng.normal(size=(n, 256)).astype(np.float32) * 0.05), pol)
+                for n in (256, 96)}  # 128 rows per shard; 48
+            one = np.asarray(jax.jit(ops.w4a8_matmul)(x, packed[256]))
+            ops.set_serving_mesh(mesh)
+            two = np.asarray(jax.jit(ops.w4a8_matmul)(x, packed[256]))
+            try:
+                jax.jit(ops.w4a8_matmul)(x, packed[96])
+                refused = False
+            except ValueError:
+                refused = True
+            ops.set_serving_mesh(None)
+            print(json.dumps({"ok": bool(np.array_equal(one, two))
+                              and refused}))
+        """)
+        assert _run_script(tmp_path, "gemm_mesh.py", body)["ok"]
+
     def test_mla_latent_pages(self, tmp_path):
         """MLA latent pages replicate; absorbed q heads shard. Token
         identity vs single-device on 2- and 4-way model meshes."""

@@ -874,9 +874,9 @@ class TestPrefillTableContract:
         tables = []
         orig = srv._decode
 
-        def spy(params, pools, toks, state, poison, samp):
+        def spy(params, pools, toks, state, poison, samp, **kw):
             tables.append(np.asarray(state.page_table))
-            return orig(params, pools, toks, state, poison, samp)
+            return orig(params, pools, toks, state, poison, samp, **kw)
 
         srv._decode = spy
         r = Request(rid=0, prompt=rng.integers(1, 64, 9).tolist(), max_new=2)

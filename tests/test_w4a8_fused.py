@@ -126,12 +126,56 @@ def test_fused_m2_lorc_odd_blocks():
                                    rtol=2e-2, atol=2e-2)
 
 
+_E2M1 = np.array([0, 0.5, 1, 1.5, 2, 3, 4, 6], np.float64)
+
+
+def _f32_dequant(w):
+    """The paper's W4A8 weight, decoded in float64 straight from the packed
+    fields: FP4-E2M1 code (low nibble = even column) x s_max x 2^-shift
+    per group — no bf16 operand, no kernel-side factoring."""
+    codes = np.asarray(w.codes)
+    nib = np.stack([codes & 0x0F, codes >> 4], -1).reshape(codes.shape[0], -1)
+    q = np.where(nib & 8, -1.0, 1.0) * _E2M1[nib & 7]
+    scale = (np.asarray(w.s_max, np.float64).reshape(-1, 1)
+             * 2.0 ** -np.asarray(w.shifts, np.float64))
+    np.testing.assert_array_equal(scale, np.asarray(w.scale, np.float64))
+    return q * np.repeat(scale, w.group_size, axis=1)
+
+
+@pytest.mark.parametrize("mnk", [(8, 256, 512), (12, 384, 2048)])
+@pytest.mark.parametrize("backend", ["ref", "pallas_interpret"])
+def test_m2_gemm_matches_f32_dequant(mnk, backend):
+    """An independent witness for the M2 factoring: the served GEMM (ref
+    oracle and kernel alike) against float64 activations-on-the-FP8-grid
+    x the float64 dequant of (codes, s_max, shifts), LoRC added in float64.
+    What may differ is bf16 rounding of the activation operand, the LoRC
+    intermediate and the bf16 output (2^-9 relative each), so both sit
+    within 2^-8 of the output's largest magnitude (a weight operand
+    rounded to bf16 as ``q * s_max * 2^-shift`` lands at ~4.5e-3)."""
+    m, n, k = mnk
+    rng = np.random.default_rng(k + n)
+    w = _pack(rng, n, k, 256, scale_mode="m2", lorc_rank=8)
+    x = jnp.asarray(rng.normal(size=(m, k)).astype(np.float32)).astype(jnp.bfloat16)
+    qx, sx = ref.act_quant_ref(x.astype(jnp.float32), "fp8_e4m3")
+    xq = np.asarray(qx, np.float64) * np.asarray(sx, np.float64)
+    want = xq @ _f32_dequant(w).T + (
+        (xq @ np.asarray(w.lorc_b, np.float64).T)
+        @ np.asarray(w.lorc_a, np.float64).T)
+    ops.set_backend(backend)
+    got = np.asarray(jax.jit(ops.w4a8_matmul)(x, w), np.float64)
+    assert np.abs(got - want).max() <= 2.0 ** -8 * np.abs(want).max()
+
+
 def test_clamp_block():
-    assert clamp_block(384, 128) == 128
-    assert clamp_block(100, 128) == 100
-    assert clamp_block(96, 64) == 48
-    assert clamp_block(5, 128) == 5
-    assert clamp_block(7, 2) == 1
+    """Blocks obey the TPU (8, 128) rule for every M the engine emits: bm
+    is a sublane multiple (M pads up to whole blocks), bn a lane-multiple
+    divisor of N or all of N (sublane multiple when N's rows contract)."""
+    assert clamp_block(384, 384, 128, 128) == (128, 128)
+    assert clamp_block(12, 2048, 128, 128) == (16, 128)
+    assert clamp_block(136, 8192, 128, 256) == (128, 256)
+    assert clamp_block(5, 100, 3, 128) == (8, 100)
+    assert clamp_block(8, 640, 8, 512) == (8, 128)
+    assert clamp_block(24, 160, 128, 32, transpose=True) == (24, 32)
 
 
 # ---------------------------------------------------------------------------
@@ -201,7 +245,7 @@ def test_moe_packed_pallas_no_dequant_matches_ref():
 
     ops.set_backend("ref")
     y_ref, _ = moe_layer(pq, x, cfg, group_size=32)
-    ops.set_backend("pallas")
+    ops.set_backend("pallas_interpret")
     with _NoDequant():
         y_pl, _ = moe_layer(pq, x, cfg, group_size=32)
     np.testing.assert_allclose(np.asarray(y_ref, np.float32),
@@ -235,7 +279,7 @@ def test_mla_decode_packed_pallas_no_dequant_matches_ref():
 
     ops.set_backend("ref")
     y_ref, _ = mla_attention(pq, x, cfg, pos, kv_cache=cache, cache_index=5)
-    ops.set_backend("pallas")
+    ops.set_backend("pallas_interpret")
     with _NoDequant():
         y_pl, _ = mla_attention(pq, x, cfg, pos, kv_cache=cache, cache_index=5)
     np.testing.assert_allclose(np.asarray(y_ref, np.float32),
@@ -258,7 +302,7 @@ def test_mla_prefill_packed_pallas_no_dequant():
 
     ops.set_backend("ref")
     y_ref, _ = mla_attention(pq, x, cfg, pos)
-    ops.set_backend("pallas")
+    ops.set_backend("pallas_interpret")
     with _NoDequant():
         y_pl, _ = mla_attention(pq, x, cfg, pos)
     np.testing.assert_allclose(np.asarray(y_ref, np.float32),
@@ -320,7 +364,7 @@ def test_ops_batched_backend_switch():
 
     ops.set_backend("ref")
     y_ref = ops.w4a8_matmul_batched(x, pw)
-    ops.set_backend("pallas")
+    ops.set_backend("pallas_interpret")
     y_pl = ops.w4a8_matmul_batched(x, pw)
     np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_pl),
                                rtol=5e-2, atol=5e-2)
