@@ -158,20 +158,21 @@ def decode_step(params, cfg, tokens, caches, cache_index, a_fmt=None):
         hidden, caches, _ = forward_hidden(
             params, cfg, batch, a_fmt=a_fmt, caches=caches, cache_index=cache_index
         )
-    if isinstance(cache_index, PagedState) and cache_index.prefill is not None:
-        nd = cache_index.lengths.shape[0]
-        h_pre = hidden[0, nd + cache_index.prefill.chunk_len[0] - 1]
-        h_last = jnp.concatenate([hidden[0, :nd], h_pre[None]], axis=0)
-    elif isinstance(cache_index, PagedState) and cache_index.chunk_len is not None:
-        h_last = hidden[:, cache_index.chunk_len[0] - 1]
-    else:
-        h_last = hidden[:, -1]
-    w = _head_w(params, cfg)
     from .layers import accum_dtype
 
-    logits = jax.lax.dot_general(
-        h_last, w, (((1,), (1,)), ((), ())), preferred_element_type=accum_dtype()
-    ).astype(jnp.float32)
+    with jax.named_scope("head"):
+        if isinstance(cache_index, PagedState) and cache_index.prefill is not None:
+            nd = cache_index.lengths.shape[0]
+            h_pre = hidden[0, nd + cache_index.prefill.chunk_len[0] - 1]
+            h_last = jnp.concatenate([hidden[0, :nd], h_pre[None]], axis=0)
+        elif isinstance(cache_index, PagedState) and cache_index.chunk_len is not None:
+            h_last = hidden[:, cache_index.chunk_len[0] - 1]
+        else:
+            h_last = hidden[:, -1]
+        w = _head_w(params, cfg)
+        logits = jax.lax.dot_general(
+            h_last, w, (((1,), (1,)), ((), ())), preferred_element_type=accum_dtype()
+        ).astype(jnp.float32)
     return logits, caches
 
 

@@ -256,9 +256,10 @@ def attention(
             nd = dec.lengths.shape[0]
             k_dec = jnp.swapaxes(k[:, :nd], 0, 1)  # (nd, 1, KV, hd)
             v_dec = jnp.swapaxes(v[:, :nd], 0, 1)
-            cache1 = append_paged(kv_cache, {"k": k_dec, "v": v_dec}, dec)
-            new_cache = append_prefill_chunk(
-                cache1, {"k": k[:, nd:], "v": v[:, nd:]}, pre)
+            with jax.named_scope("kv_write"):
+                cache1 = append_paged(kv_cache, {"k": k_dec, "v": v_dec}, dec)
+                new_cache = append_prefill_chunk(
+                    cache1, {"k": k[:, nd:], "v": v[:, nd:]}, pre)
             q_dec = jnp.swapaxes(q[:, :nd], 0, 1)
             o_dec = ops.paged_decode_attn(
                 q_dec[:, 0], new_cache, dec.page_table, dec.lengths + 1,
@@ -282,7 +283,9 @@ def attention(
             # length masks replace the engine-level synchronized index.
             from repro.kernels import ops
 
-            new_cache = append_paged(kv_cache, {"k": k, "v": v}, cache_index)
+            with jax.named_scope("kv_write"):
+                new_cache = append_paged(kv_cache, {"k": k, "v": v},
+                                         cache_index)
             o = ops.paged_decode_attn(
                 q[:, 0], new_cache, cache_index.page_table,
                 cache_index.lengths + 1, window=cfg.window,
@@ -301,8 +304,9 @@ def attention(
             # from pages.
             assert causal, "streaming paged prefill assumes causal decode LMs"
             assert b == 1, "streaming paged prefill is row-wise (batch 1)"
-            new_cache = append_prefill_chunk(kv_cache, {"k": k, "v": v},
-                                             cache_index)
+            with jax.named_scope("kv_write"):
+                new_cache = append_prefill_chunk(kv_cache, {"k": k, "v": v},
+                                                 cache_index)
             o = _paged_chunk_attn(q, k, v, new_cache, cache_index, g,
                                   cfg.window)
         o = o.reshape(b, s, h * hd)

@@ -119,10 +119,12 @@ def mla_attention(
             dec = cache_index._replace(prefill=None)
             ckv_dec = jnp.swapaxes(c_kv[:, :nd], 0, 1)  # (nd, 1, r)
             kr_dec = jnp.swapaxes(k_rope[:, :nd], 0, 1)
-            cache1 = append_paged(
-                kv_cache, {"ckv": ckv_dec, "krope": kr_dec}, dec)
-            new_cache = append_prefill_chunk(
-                cache1, {"ckv": c_kv[:, nd:], "krope": k_rope[:, nd:]}, pre)
+            with jax.named_scope("kv_write"):
+                cache1 = append_paged(
+                    kv_cache, {"ckv": ckv_dec, "krope": kr_dec}, dec)
+                new_cache = append_prefill_chunk(
+                    cache1, {"ckv": c_kv[:, nd:], "krope": k_rope[:, nd:]},
+                    pre)
             sc = s - nd
             hist, hist_len = gather_history(new_cache, pre, sc)
             start = pre.lengths[0]
@@ -139,9 +141,10 @@ def mla_attention(
                  jnp.tril(jnp.ones((sc, sc), jnp.bool_))], axis=1)
             pmsk4 = jnp.where(ok, 0.0, -1e30)[None, None].astype(jnp.float32)
         elif s == 1 and cache_index.chunk_len is None:
-            new_cache = append_paged(
-                kv_cache, {"ckv": c_kv, "krope": k_rope}, cache_index
-            )
+            with jax.named_scope("kv_write"):
+                new_cache = append_paged(
+                    kv_cache, {"ckv": c_kv, "krope": k_rope}, cache_index
+                )
         else:
             # streaming prefill: write the page-aligned chunk in-graph, then
             # attend over the gathered table + the chunk's own exact latents
@@ -152,9 +155,10 @@ def mla_attention(
             # chunk masks plain tril (bucketed pad columns are only visible
             # to pad rows, whose outputs are discarded).
             assert b == 1, "streaming paged prefill is row-wise (batch 1)"
-            new_cache = append_prefill_chunk(
-                kv_cache, {"ckv": c_kv, "krope": k_rope}, cache_index
-            )
+            with jax.named_scope("kv_write"):
+                new_cache = append_prefill_chunk(
+                    kv_cache, {"ckv": c_kv, "krope": k_rope}, cache_index
+                )
             hist, hist_len = gather_history(new_cache, cache_index, s)
             start = cache_index.lengths[0]
             ckv = c_kv.astype(jnp.bfloat16)

@@ -151,11 +151,12 @@ def block_apply(
     paged = isinstance(cache_index, PagedState)
 
     if seg.mixer == "gqa":
-        h, new_kv = attention(
-            pm["attn"], norm(pm["ln"], x, nk, cfg.norm_eps), cfg, positions,
-            kv_cache=None if cache is None else cache["kv"],
-            cache_index=cache_index, a_fmt=a_fmt,
-        )
+        with jax.named_scope("attn"):
+            h, new_kv = attention(
+                pm["attn"], norm(pm["ln"], x, nk, cfg.norm_eps), cfg,
+                positions, kv_cache=None if cache is None else cache["kv"],
+                cache_index=cache_index, a_fmt=a_fmt,
+            )
         x = x + h
         if cache is not None:
             new_cache = dict(cache, kv=new_kv)
@@ -164,10 +165,12 @@ def block_apply(
                 # write-once cross pages: the engine ran the encoder at
                 # admission and quantized its K/V into cache["cross"];
                 # decode and prefill chunks only ever read them
-                h = paged_cross_attention(
-                    pm["cross"], norm(pm["ln_cross"], x, nk, cfg.norm_eps),
-                    cfg, positions, cache["cross"], cache_index, a_fmt=a_fmt,
-                )
+                with jax.named_scope("attn"):
+                    h = paged_cross_attention(
+                        pm["cross"],
+                        norm(pm["ln_cross"], x, nk, cfg.norm_eps), cfg,
+                        positions, cache["cross"], cache_index, a_fmt=a_fmt,
+                    )
             else:
                 is_decode = cache is not None and x.shape[1] == 1
                 if is_decode:  # prefill computed + stored these from enc_out
@@ -186,11 +189,12 @@ def block_apply(
                 )
             x = x + h
     elif seg.mixer == "mla":
-        h, new_kv = mla_attention(
-            pm["attn"], norm(pm["ln"], x, nk, cfg.norm_eps), cfg, positions,
-            kv_cache=None if cache is None else cache["kv"],
-            cache_index=cache_index, a_fmt=a_fmt,
-        )
+        with jax.named_scope("attn"):
+            h, new_kv = mla_attention(
+                pm["attn"], norm(pm["ln"], x, nk, cfg.norm_eps), cfg,
+                positions, kv_cache=None if cache is None else cache["kv"],
+                cache_index=cache_index, a_fmt=a_fmt,
+            )
         x = x + h
         if cache is not None:
             new_cache = dict(cache, kv=new_kv)
@@ -233,30 +237,32 @@ def block_apply(
     else:
         raise ValueError(seg.mixer)
 
-    if seg.ffn != "none":
+    if seg.ffn == "mlp":
         pf = p["ffn"]
-        if seg.ffn == "mlp":
+        with jax.named_scope("mlp"):
             x = x + mlp(pf["mlp"], norm(pf["ln"], x, nk, cfg.norm_eps), cfg, a_fmt=a_fmt)
-        else:
-            from .moe_a2a import get_moe_impl, moe_decode_ep, moe_layer_a2a
+    elif seg.ffn == "moe":
+        from .moe_a2a import get_moe_impl, moe_decode_ep, moe_layer_a2a
 
-            kind, mesh = get_moe_impl()
+        pf = p["ffn"]
+        kind, mesh = get_moe_impl()
+        # Serving (paged) routes per-token: group_size=1 puts every
+        # token in its own dispatch group, so capacity never drops an
+        # assignment and each token's experts depend only on its own
+        # hidden state. Batch composition — which rows share the
+        # program, decode lanes vs a piggybacked prefill chunk, chunk
+        # bucketing — can then never change a token's routing, which
+        # is what makes serving outputs independent of batchmates and
+        # the mixed engine bit-identical to the alternating one.
+        # Training keeps the capacity-bounded grouped dispatch.
+        gs = 1 if paged else 1024
+        ok_a2a = (
+            kind == "a2a" and mesh is not None and not paged
+            and x.shape[1] % mesh.shape.get("model", 1) == 0
+            and x.shape[0] % mesh.shape.get("data", 1) == 0
+        )
+        with jax.named_scope("mlp"):
             x_ln = norm(pf["ln"], x, nk, cfg.norm_eps)
-            # Serving (paged) routes per-token: group_size=1 puts every
-            # token in its own dispatch group, so capacity never drops an
-            # assignment and each token's experts depend only on its own
-            # hidden state. Batch composition — which rows share the
-            # program, decode lanes vs a piggybacked prefill chunk, chunk
-            # bucketing — can then never change a token's routing, which
-            # is what makes serving outputs independent of batchmates and
-            # the mixed engine bit-identical to the alternating one.
-            # Training keeps the capacity-bounded grouped dispatch.
-            gs = 1 if paged else 1024
-            ok_a2a = (
-                kind == "a2a" and mesh is not None and not paged
-                and x.shape[1] % mesh.shape.get("model", 1) == 0
-                and x.shape[0] % mesh.shape.get("data", 1) == 0
-            )
             if ok_a2a:  # MTP's S-1 path etc. fall back to einsum dispatch
                 h, aux = moe_layer_a2a(pf["moe"], x_ln, cfg, mesh, a_fmt=a_fmt)
             elif kind == "ep_decode" and mesh is not None:
@@ -348,7 +354,12 @@ def _segment_scan(p_stack, x, cfg, seg, positions, caches, cache_index, a_fmt, e
     if remat:
         body = jax.checkpoint(body)
 
-    (x, aux), new_caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), (p_stack, caches))
+    # device ops under "layers" and in none of the body's scopes are the
+    # scan's own traffic: each layer's weight and cache slices coming in,
+    # the caches going back out
+    with jax.named_scope("layers"):
+        (x, aux), new_caches = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32)), (p_stack, caches))
     return x, aux, new_caches
 
 
@@ -370,7 +381,8 @@ def lm_forward(
     """
     from repro.runtime.kv_cache import PagedState
 
-    x = _embed_tokens(params, cfg, tokens, embeds_prefix)
+    with jax.named_scope("embed"):
+        x = _embed_tokens(params, cfg, tokens, embeds_prefix)
     b, s = x.shape[:2]
     paged = isinstance(cache_index, PagedState)
     if positions is None:
@@ -390,13 +402,14 @@ def lm_forward(
             offset = 0 if cache_index is None else cache_index
             positions = jnp.arange(s) + offset
     if cfg.pos_embedding == "learned":
-        if paged:
-            x = x + jnp.take(params["pos_embed"], positions, axis=0).astype(x.dtype)
-        else:
-            x = x + jax.lax.dynamic_slice_in_dim(
-                params["pos_embed"], 0 if cache_index is None else cache_index,
-                s, axis=0,
-            )[None].astype(x.dtype)
+        with jax.named_scope("embed"):
+            if paged:
+                x = x + jnp.take(params["pos_embed"], positions, axis=0).astype(x.dtype)
+            else:
+                x = x + jax.lax.dynamic_slice_in_dim(
+                    params["pos_embed"], 0 if cache_index is None else cache_index,
+                    s, axis=0,
+                )[None].astype(x.dtype)
 
     segs = segments_for(cfg)
     new_caches = []
@@ -409,7 +422,8 @@ def lm_forward(
         )
         aux_total = aux_total + aux
         new_caches.append(nc)
-    x = norm(params["final_ln"], x, cfg.norm_kind, cfg.norm_eps)
+    with jax.named_scope("head"):
+        x = norm(params["final_ln"], x, cfg.norm_kind, cfg.norm_eps)
     return x, (new_caches if caches is not None else None), aux_total
 
 

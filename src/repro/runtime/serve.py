@@ -158,7 +158,8 @@ def _decode_step(params, caches, tokens, cache_index, poison, samp,
                                             cache_index, a_fmt=a_fmt)
     logits = jnp.where(poison[:, None], jnp.float32(jnp.nan), logits)
     row_ok = jnp.all(jnp.isfinite(logits), axis=-1)
-    nxt = smp.sample_tokens(logits, *samp)
+    with jax.named_scope("sample"):
+        nxt = smp.sample_tokens(logits, *samp)
     return nxt, row_ok, caches
 
 
@@ -173,6 +174,13 @@ def _encode_cross_jit(params, frames, caches, cross_table, cfg, a_fmt,
     with _backend_scope(backend):
         return models.encode_cross_pages(params, cfg, frames, caches,
                                          cross_table, a_fmt=a_fmt)
+
+
+def _span(name: str):
+    """A host span of the engine step (``serve.*``) on the profiler's
+    clock. While no trace is captured it is an object that costs about a
+    microsecond and records nothing."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 @contextlib.contextmanager
@@ -467,7 +475,11 @@ class RequestResult:
     ``"truncated"`` (retired at the max_seq bound with fewer tokens) or
     ``"failed"`` (quarantined; ``error`` has the diagnostic).
     ``token_times`` holds the per-token host timestamps the engine
-    recorded at decode — ``ttft``/``itl`` derive latency from them."""
+    recorded at decode — ``ttft``/``itl`` derive latency from them.
+    ``admitted_at`` (first granted a slot) and ``first_chunk_at`` (first
+    prefill chunk launched) split the time to first token into queue,
+    prefill-lane wait and prefill; None where the request never got
+    that far."""
 
     rid: int
     tokens: Tuple[int, ...]
@@ -478,6 +490,8 @@ class RequestResult:
     evictions: int  # times its host spill was dropped (re-prefilled)
     submitted_at: float
     token_times: Tuple[float, ...]
+    admitted_at: Optional[float] = None
+    first_chunk_at: Optional[float] = None
 
     @property
     def ok(self) -> bool:
@@ -522,6 +536,11 @@ class Request:
     since: int = 0  # server-managed: step this request entered the wait line
     seq: int = 0  # server-managed: global arrival sequence (tie-break)
     t_submit: float = 0.0  # server-managed: perf_counter at submit()
+    # server-managed, set once (preemption, resume and eviction keep them):
+    # perf_counter when first granted a slot, and just before the launch
+    # of the program carrying its first prefill chunk
+    t_admit: Optional[float] = None
+    t_first_chunk: Optional[float] = None
     token_times: list = dataclasses.field(default_factory=list)
     _frames_digest: Optional[str] = dataclasses.field(
         default=None, repr=False, compare=False)
@@ -552,7 +571,8 @@ class Request:
             rid=self.rid, tokens=tuple(self.out), status=self.status,
             error=self.error, prompt_len=len(self.prompt),
             preemptions=self.preemptions, evictions=self.evictions,
-            submitted_at=self.t_submit, token_times=tuple(self.token_times))
+            submitted_at=self.t_submit, token_times=tuple(self.token_times),
+            admitted_at=self.t_admit, first_chunk_at=self.t_first_chunk)
 
 
 @dataclasses.dataclass
@@ -655,14 +675,41 @@ class Server:
         self.preempted: List[_Spill] = []
         self.finished: List[Request] = []
         self.stats = {
-            "steps": 0, "slot_steps": 0, "decoded_tokens": 0,
-            "programs": 0,  # every jitted launch: encode/prefill/decode/mixed
-            "prefill_tokens": 0, "preemptions": 0, "resumes": 0,
-            "pages_stolen": 0, "spill_evictions": 0, "truncated": 0,
-            "prefix_hit_pages": 0, "prefix_hit_tokens": 0,
-            "prefix_reclaims": 0, "resume_fallbacks": 0,
-            "failed": 0, "spill_integrity_failures": 0,
-            "fp4_frozen_pages": 0,  # cumulative freeze-time transcodes
+            # engine steps that launched a decode or mixed program
+            "steps": 0,
+            # decoding rows, summed over steps
+            "slot_steps": 0,
+            # tokens emitted by decoding rows (not prefill seed tokens)
+            "decoded_tokens": 0,
+            # every jitted launch: encode/prefill/decode/mixed
+            "programs": 0,
+            # steps whose program carried a prefill chunk (mixed engine;
+            # the alternating engine prefills in admission, outside steps)
+            "prefill_steps": 0,
+            # context tokens streamed through prefill programs
+            "prefill_tokens": 0,
+            # preemptions (a running request's pages stolen), spill restores
+            "preemptions": 0,
+            "resumes": 0,
+            # private pages taken from preempted requests
+            "pages_stolen": 0,
+            # host spills dropped by the spill budget (re-prefilled)
+            "spill_evictions": 0,
+            # requests retired at the max_seq bound short of max_new
+            "truncated": 0,
+            # prompt pages, and their tokens, mapped from the prefix cache
+            "prefix_hit_pages": 0,
+            "prefix_hit_tokens": 0,
+            # parked prefix-cache pages reclaimed by the allocator
+            "prefix_reclaims": 0,
+            # resumes whose shared prefix was reclaimed (tail re-prefill)
+            "resume_fallbacks": 0,
+            # requests quarantined (status "failed")
+            "failed": 0,
+            # spills whose checksum failed on resume (re-prefilled)
+            "spill_integrity_failures": 0,
+            # freeze-time FP8 -> FP4 page transcodes
+            "fp4_frozen_pages": 0,
         }
         self._step_no = 0
         # engine tick: advances every step() *call*, decoded or not — the
@@ -1374,6 +1421,8 @@ class Server:
             return False
         self.queue.pop(0)
         self.active[slot] = req
+        if req.t_admit is None:
+            req.t_admit = time.perf_counter()
         self._slot_seq[slot] = req.seq
         self._slot_since[slot] = self._step_no
         if self._has_pages:
@@ -1509,6 +1558,8 @@ class Server:
             state = self._state_for(slice(slot, slot + 1),
                                     np.asarray([pos], np.int32), chunk_len)
             state = state._replace(page_table=jnp.asarray(table))
+            if req.t_first_chunk is None:
+                req.t_first_chunk = time.perf_counter()
             with self._trace_scope():
                 nxt, row_ok, pools = self._decode(
                     self.params, self.pools, jnp.asarray([toks], jnp.int32),
@@ -2044,7 +2095,36 @@ class Server:
         for enc-dec the cross table / for recurrent families the slab
         ids) ride into the jitted step as inputs — per-row positions and
         length masks, one fixed-shape program per (chunk bucket, table
-        bucket). Returns True if any slot made progress."""
+        bucket). Returns True if any slot made progress.
+
+        Host spans on the profiler's clock: ``serve.step`` around the
+        whole call; inside it ``serve.schedule`` (admission — with the
+        alternating engine's whole prefill — page growth and the prefill
+        pick), then ``serve.prepare`` (host arrays and their transfer),
+        ``serve.launch`` (the call into the jitted step), ``serve.sync``
+        (the wait for its results), ``serve.commit`` (emit, retire,
+        register) and, when it runs, ``serve.audit``."""
+        with _span("serve.step"):
+            with _span("serve.schedule"):
+                plan = self._schedule()
+            if plan is None:
+                return False
+            pf_slot, decoding, pmask = plan
+            if pf_slot is not None:
+                self._step_mixed(pf_slot, decoding, pmask)
+            else:
+                self._step_decode(pmask)
+            if self.audit_every and self._step_no % self.audit_every == 0:
+                with _span("serve.audit"):
+                    self.audit()
+        return True
+
+    def _schedule(self):
+        """The step's host-side decisions: spill budget, admission, page
+        growth and the mixed engine's prefill pick. Returns None when no
+        slot is active (nothing to launch), else ``(pf_slot, decoding,
+        pmask)``: the slot whose chunk rides this step (None: decode
+        only), the decoding slots, and the fault plan's poison mask."""
         self._tick += 1
         self._alloc_faulted = (self.faults is not None
                                and self.faults.alloc_blocked(self._tick))
@@ -2063,7 +2143,7 @@ class Server:
             self._grow_for_chunk(pf_slot)
             pf_slot = self._pick_prefill_slot()  # a steal may have hit it
         if not any(self.active):
-            return False
+            return None
         self._step_no += 1
         self.stats["steps"] += 1
         # decoding rows are the active slots at their context target; in
@@ -2084,41 +2164,40 @@ class Server:
                 frozen_base=self._frozen_base)
         pmask = (self.faults.poison_rows(self._step_no, self.slots)
                  if self.faults is not None else None)
-        if pf_slot is not None:
-            self._step_mixed(pf_slot, decoding, pmask)
-        else:
-            self._step_decode(pmask)
-        if self.audit_every and self._step_no % self.audit_every == 0:
-            self.audit()
-        return True
+        return pf_slot, decoding, pmask
 
     def _step_decode(self, pmask):
         """Pure-decode engine step: every active row is at its context
         target and decodes one token."""
-        tok = np.zeros((self.slots, 1), dtype=np.int32)
-        for s, req in enumerate(self.active):
-            if req is not None and req.out:
-                tok[s, 0] = req.out[-1]
-            if req is not None:
-                # count = tokens emitted so far = RNG index of this draw;
-                # recomputed from the request each step, so the stream
-                # position survives steals and resumes for free
-                smp.fill_slot(self._samp, s, req.sampling, len(req.out))
-            else:
-                smp.clear_slot(self._samp, s)
-        poison = (jnp.asarray(pmask) if pmask is not None and pmask.any()
-                  else self._no_poison)
-        state = self._state_for(slice(None), self.lengths)
-        with self._trace_scope():
+        with _span("serve.prepare"):
+            tok = np.zeros((self.slots, 1), dtype=np.int32)
+            for s, req in enumerate(self.active):
+                if req is not None and req.out:
+                    tok[s, 0] = req.out[-1]
+                if req is not None:
+                    # count = tokens emitted so far = RNG index of this
+                    # draw; recomputed from the request each step, so the
+                    # stream position survives steals and resumes for free
+                    smp.fill_slot(self._samp, s, req.sampling, len(req.out))
+                else:
+                    smp.clear_slot(self._samp, s)
+            poison = (jnp.asarray(pmask) if pmask is not None and pmask.any()
+                      else self._no_poison)
+            state = self._state_for(slice(None), self.lengths)
+            tok = jnp.asarray(tok)
+            samp = smp.as_tuple(self._samp)
+        with _span("serve.launch"), self._trace_scope():
             nxt_dev, row_ok, self.pools = self._decode(
-                self.params, self.pools, jnp.asarray(tok), state, poison,
-                smp.as_tuple(self._samp), backend=self._backend())
+                self.params, self.pools, tok, state, poison, samp,
+                backend=self._backend())
         self.stats["programs"] += 1
-        nxt = np.asarray(nxt_dev)
-        okrow = np.asarray(row_ok)
-        for s, req in enumerate(self.active):
-            if req is not None:
-                self._finish_decode_row(s, req, okrow[s], nxt[s], pmask)
+        with _span("serve.sync"):
+            nxt = np.asarray(nxt_dev)
+            okrow = np.asarray(row_ok)
+        with _span("serve.commit"):
+            for s, req in enumerate(self.active):
+                if req is not None:
+                    self._finish_decode_row(s, req, okrow[s], nxt[s], pmask)
 
     def _finish_decode_row(self, s: int, req: Request, ok: bool, nxt,
                            pmask):
@@ -2167,70 +2246,82 @@ class Server:
         ctx = list(req.prompt) + list(req.out[:-1])
         n = len(ctx)
         pos = int(self.lengths[pf_slot])
-        take, padded, w, table = self._chunk_plan(
-            pf_slot, n, pos, self.prefill_token_budget)
-        is_decoding = np.zeros((self.slots,), bool)
-        is_decoding[decoding] = True
-        tok = np.zeros((1, self.slots + padded), np.int32)
-        dec_lengths = np.where(is_decoding, self.lengths, 0).astype(np.int32)
-        for s in range(self.slots):
-            r = self.active[s]
-            if r is not None and is_decoding[s]:
-                tok[0, s] = r.out[-1]
-                smp.fill_slot(self._samp_m, s, r.sampling, len(r.out))
+        with _span("serve.prepare"):
+            take, padded, w, table = self._chunk_plan(
+                pf_slot, n, pos, self.prefill_token_budget)
+            is_decoding = np.zeros((self.slots,), bool)
+            is_decoding[decoding] = True
+            tok = np.zeros((1, self.slots + padded), np.int32)
+            dec_lengths = np.where(is_decoding, self.lengths,
+                                   0).astype(np.int32)
+            for s in range(self.slots):
+                r = self.active[s]
+                if r is not None and is_decoding[s]:
+                    tok[0, s] = r.out[-1]
+                    smp.fill_slot(self._samp_m, s, r.sampling, len(r.out))
+                else:
+                    smp.clear_slot(self._samp_m, s)
+            tok[0, self.slots: self.slots + take] = ctx[pos: pos + take]
+            # the chunk row samples at RNG index len(out): consumed as the
+            # stream's seed token only by a fresh request's final chunk —
+            # intermediate (and resume re-prefill) draws are discarded, and
+            # the stateless fold_in keying means the index is never burned
+            smp.fill_slot(self._samp_m, self.slots, req.sampling,
+                          len(req.out))
+            if pmask is not None and pmask.any():
+                # the chunk row inherits pf_slot's poison: a fault injected
+                # into the streaming request mid-prefill must trip the
+                # chunk row's sentinel (its decode-lane row is garbage and
+                # ignored)
+                poison = jnp.asarray(
+                    np.concatenate([pmask, pmask[pf_slot:pf_slot + 1]]))
             else:
-                smp.clear_slot(self._samp_m, s)
-        tok[0, self.slots: self.slots + take] = ctx[pos: pos + take]
-        # the chunk row samples at RNG index len(out): consumed as the
-        # stream's seed token only by a fresh request's final chunk —
-        # intermediate (and resume re-prefill) draws are discarded, and
-        # the stateless fold_in keying means the index is never burned
-        smp.fill_slot(self._samp_m, self.slots, req.sampling, len(req.out))
-        if pmask is not None and pmask.any():
-            # the chunk row inherits pf_slot's poison: a fault injected
-            # into the streaming request mid-prefill must trip the chunk
-            # row's sentinel (its decode-lane row is garbage and ignored)
-            poison = jnp.asarray(
-                np.concatenate([pmask, pmask[pf_slot:pf_slot + 1]]))
-        else:
-            poison = self._no_poison_m
-        pre_state = kvc.PagedState(
-            page_table=jnp.asarray(table),
-            lengths=jnp.asarray([pos], np.int32),
-            chunk_len=jnp.asarray([take], jnp.int32))
-        state = self._state_for(slice(None), dec_lengths)
-        state = state._replace(prefill=pre_state)
-        with self._trace_scope():
+                poison = self._no_poison_m
+            pre_state = kvc.PagedState(
+                page_table=jnp.asarray(table),
+                lengths=jnp.asarray([pos], np.int32),
+                chunk_len=jnp.asarray([take], jnp.int32))
+            state = self._state_for(slice(None), dec_lengths)
+            state = state._replace(prefill=pre_state)
+            tok = jnp.asarray(tok)
+            samp = smp.as_tuple(self._samp_m)
+        if req.t_first_chunk is None:
+            req.t_first_chunk = time.perf_counter()
+        with _span("serve.launch"), self._trace_scope():
             nxt_dev, row_ok, self.pools = self._decode(
-                self.params, self.pools, jnp.asarray(tok), state, poison,
-                smp.as_tuple(self._samp_m), backend=self._backend())
+                self.params, self.pools, tok, state, poison, samp,
+                backend=self._backend())
         self.stats["programs"] += 1
+        self.stats["prefill_steps"] += 1
         self.prefill_traces.add((padded, w))
-        nxt = np.asarray(nxt_dev)
-        okrow = np.asarray(row_ok)
-        self.lengths[pf_slot] = pos + take
-        self.stats["prefill_tokens"] += take
-        if not okrow[self.slots]:
-            # non-finite logits in the chunk row: quarantine the streaming
-            # request alone. Its pages are NOT registered in the prefix
-            # index (frozen garbage would poison every future hit) and no
-            # seed token is appended — retire through the normal path so
-            # pages/slab accounting stays intact
-            if pmask is not None and pmask[pf_slot]:
-                self.faults.note_nan(self._step_no, pf_slot, req.rid)
-            self._fail_slot(pf_slot, req,
-                            f"non-finite logits during prefill of request "
-                            f"{req.rid} ({n} context tokens)",
-                            scrub_null=True)
-        elif pos + take == n:
-            if self._prefix is not None:
-                self._register_prefix(pf_slot, req)
-            if not req.out:  # fresh: the final chunk's draw seeds decode
-                self._emit_token(req, int(nxt[self.slots]))
-        for s in decoding:
-            r = self.active[s]
-            if r is not None:
-                self._finish_decode_row(s, r, okrow[s], nxt[s], pmask)
+        with _span("serve.sync"):
+            nxt = np.asarray(nxt_dev)
+            okrow = np.asarray(row_ok)
+        with _span("serve.commit"):
+            self.lengths[pf_slot] = pos + take
+            self.stats["prefill_tokens"] += take
+            if not okrow[self.slots]:
+                # non-finite logits in the chunk row: quarantine the
+                # streaming request alone. Its pages are NOT registered in
+                # the prefix index (frozen garbage would poison every
+                # future hit) and no seed token is appended — retire
+                # through the normal path so pages/slab accounting stays
+                # intact
+                if pmask is not None and pmask[pf_slot]:
+                    self.faults.note_nan(self._step_no, pf_slot, req.rid)
+                self._fail_slot(pf_slot, req,
+                                f"non-finite logits during prefill of "
+                                f"request {req.rid} ({n} context tokens)",
+                                scrub_null=True)
+            elif pos + take == n:
+                if self._prefix is not None:
+                    self._register_prefix(pf_slot, req)
+                if not req.out:  # fresh: the final chunk's draw seeds decode
+                    self._emit_token(req, int(nxt[self.slots]))
+            for s in decoding:
+                r = self.active[s]
+                if r is not None:
+                    self._finish_decode_row(s, r, okrow[s], nxt[s], pmask)
 
     def run_until_drained(self, max_steps: int = 10_000) -> List[RequestResult]:
         """Step until queue, preempted set and slots are all empty; returns
