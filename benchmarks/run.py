@@ -146,17 +146,16 @@ def kernel_microbench(tiny: bool = False):
         ids = np.arange(r * pp, (r + 1) * pp, dtype=np.int32)
         pt[r] = ids
         pool = kvc.splice_prefill(pool, {"k": kc, "v": kc}, ids, pp * page)
-    layer = {k: v[0] for k, v in pool.items()}
     qd = jnp.asarray(rng.normal(size=(b, kv * 2, hd)).astype(np.float32))
     lens = jnp.full((b,), pp * page, jnp.int32)
     ptj = jnp.asarray(pt)
     prev = kops.get_backend()
     try:
         kops.set_backend("ref")
-        t_ref = timed(jax.jit(lambda q: kops.paged_decode_attn(q, layer, ptj, lens)), qd)
+        t_ref = timed(jax.jit(lambda q: kops.paged_decode_attn(q, pool, 0, ptj, lens)), qd)
         rows.append(("kernel/paged_decode_attn_ref", t_ref, 0.0))
         kops.set_backend("pallas_interpret")
-        t_pal = timed(lambda q: kops.paged_decode_attn(q, layer, ptj, lens), qd)
+        t_pal = timed(lambda q: kops.paged_decode_attn(q, pool, 0, ptj, lens), qd)
         rows.append(("kernel/paged_decode_attn_pallas_interp", t_pal, 0.0))
     finally:
         kops.set_backend(prev)
@@ -178,7 +177,6 @@ def kernel_microbench(tiny: bool = False):
         ids = np.arange(r * ppp, (r + 1) * ppp, dtype=np.int32)
         pt2[r] = ids
         pool2 = kvc.splice_prefill(pool2, {"k": kc2, "v": kc2}, ids, t_true)
-    layer2 = {k: v[0] for k, v in pool2.items()}
     q2 = jnp.asarray(rng.normal(size=(pb, pkv * pg2, phd)).astype(np.float32))
     lens2 = jnp.full((pb,), t_true, jnp.int32)
     pt2j = jnp.asarray(pt2)
@@ -203,7 +201,7 @@ def kernel_microbench(tiny: bool = False):
     try:
         kops.set_backend("ref")
         t_paged = timed(jax.jit(
-            lambda q: kops.paged_decode_attn(q, layer2, pt2j, lens2)), q2)
+            lambda q: kops.paged_decode_attn(q, pool2, 0, pt2j, lens2)), q2)
         t_mono = timed(jax.jit(legacy_decode), q2)
     finally:
         kops.set_backend(prev)
@@ -238,7 +236,6 @@ def kernel_microbench(tiny: bool = False):
         mpt[r] = ids
         mpool = kvc.splice_prefill(mpool, {"ckv": ckv_src, "krope": kr_src},
                                    ids, mt)
-    mlayer = {k: v[0] for k, v in mpool.items()}
     mptj = jnp.asarray(mpt)
     mlens = jnp.full((mb,), mt, jnp.int32)
     mscale = 1.0 / float(m_nope + mdr) ** 0.5
@@ -253,14 +250,14 @@ def kernel_microbench(tiny: bool = False):
     def mla_latent(qn, qr):  # absorbed: q/out fold through wk_b/wv_b
         q_lat = jnp.einsum("bhn,hnr->bhr", qn.astype(jnp.bfloat16), wk_b,
                            preferred_element_type=jnp.float32)
-        ctx = kops.paged_mla_decode_attn(q_lat, qr, mlayer, mptj, mlens,
+        ctx = kops.paged_mla_decode_attn(q_lat, qr, mpool, 0, mptj, mlens,
                                          mscale)
         return jnp.einsum("bhr,hvr->bhv", ctx.astype(jnp.bfloat16), wv_b,
                           preferred_element_type=jnp.float32)
 
     def mla_materialized(qn, qr):  # expand pages to per-head K/V, attend
-        ckv = kvc.gather_pages(mlayer, "ckv", mstate).astype(jnp.bfloat16)
-        krope = kvc.gather_pages(mlayer, "krope", mstate).astype(jnp.bfloat16)
+        ckv = kvc.gather_pages(mpool, 0, "ckv", mstate).astype(jnp.bfloat16)
+        krope = kvc.gather_pages(mpool, 0, "krope", mstate).astype(jnp.bfloat16)
         k_nope = jnp.einsum("btr,hnr->bthn", ckv, wk_b,
                             preferred_element_type=jnp.float32)
         vh = jnp.einsum("btr,hvr->bthv", ckv, wv_b,

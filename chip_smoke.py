@@ -279,16 +279,15 @@ def live_decode_gap(server, seed: int, mesh=None):
         size=(len(rows), cfg.n_heads, cfg.resolved_head_dim)).astype(np.float32))
 
     def attn(backend, m):
-        return jax.jit(lambda layer, q, pt, lens: _under(
-            backend, m, ops.paged_decode_attn, q, layer, pt, lens))
+        return jax.jit(lambda pool, layer, q, pt, lens: _under(
+            backend, m, ops.paged_decode_attn, q, pool, layer, pt, lens))
 
     pal, ref = attn("pallas", mesh), attn("ref", None)
     gaps = []
     for i in range(cfg.n_layers):
-        layer = jax.tree.map(lambda a: a[i], kv)
-        o_pal = np.asarray(pal(layer, q, pt, lens))
+        o_pal = np.asarray(pal(kv, i, q, pt, lens))
         with jax.default_matmul_precision("highest"):
-            o_ref = np.asarray(ref(layer, q, pt, lens))
+            o_ref = np.asarray(ref(kv, i, q, pt, lens))
         gaps.append(np.abs(o_pal - o_ref).max() / np.abs(o_ref).max())
     return np.asarray(gaps), rows, np.asarray(lens)
 

@@ -5,10 +5,15 @@ cache in its *deployed* form — packed FP8 E4M3 pages with per-(page, head)
 M2 scales, plus (mixed-precision pools) a packed FP4 E2M1 frozen region —
 and never materializes a dequantized cache in HBM:
 
-  * the page table and per-row true lengths ride in as scalar-prefetch
-    operands (SMEM); each grid step's BlockSpec index_map *gathers* its page
-    straight from the pool via ``page_table[b, j]`` — the DMA engine fetches
-    exactly the pages a row owns, in page-table order,
+  * the page table, per-row true lengths and the layer index ride in as
+    scalar-prefetch operands (SMEM); each grid step's BlockSpec index_map
+    *gathers* its page straight from the stacked pool via
+    ``(layer, page_table[b, j])`` — the DMA engine fetches exactly the pages
+    a row owns, in page-table order, and a lane-dense store is never sliced
+    (a sliced operand would make XLA copy the layer's whole store); only a
+    store narrower than a lane tile is sliced to the layer, since the TPU
+    lays it out with another dim minor and XLA would otherwise relay the
+    whole stack out for the kernel (see ``_code_operand``),
   * codes are dequantized in VMEM with the exponent-add scale apply
     (kernels.common.decode_fp8: per-head shift k is an integer add on the
     exponent; the full-precision s_max multiplies once per page),
@@ -109,7 +114,7 @@ def _kernel(*refs, page, pp, scale, classes, base, nfz, g, window):
     own element order, and the wrapper sums them."""
     refs = list(refs)
     take = lambda n: [refs.pop(0) for _ in range(n)]
-    pt_ref, len_ref = take(2)
+    pt_ref, len_ref, _layer_ref = take(3)
     smax = [take(2) if f.quantized else None for f in classes]
     q_nat = refs.pop(0) if any(not f.packed for f in classes) else None
     q_pair = take(2) if any(f.packed for f in classes) else None
@@ -191,26 +196,50 @@ def _kernel(*refs, page, pp, scale, classes, base, nfz, g, window):
                 o[0] = a[...] * inv
 
 
+_LANES = 128
+
+
+def _code_operand(store, layer, pmap):
+    """The operand and index map through which a kernel reads one layer of
+    a stacked code store ``(L, rows, page, ...)``. A store whose minor dim
+    fills whole lane tiles is read in place: ``pmap`` addresses (layer,
+    page). The TPU lays a narrower store (MLA's ``krope``, FP4 codes of a
+    64-wide head) out with another dim minor, which a per-page block cannot
+    be DMA'd from, so XLA would copy the whole stack into the kernel's
+    layout on every call: that store's layer is sliced first, and only the
+    slice is copied."""
+    if store.shape[-1] % _LANES == 0:
+        return store, pmap
+    one = jax.lax.dynamic_slice_in_dim(store, layer[0], 1)
+    return one, lambda *ix: (0,) + tuple(pmap(*ix)[1:])
+
+
 @functools.partial(jax.jit, static_argnames=("fmt", "frozen", "window",
                                              "interpret"))
 def paged_decode_attn_pallas(q, k_pages, v_pages, k_smax, k_shift, v_smax,
-                             v_shift, page_table, kv_lens,
+                             v_shift, page_table, kv_lens, layer,
                              fmt=None, frozen=None,
                              k_fz=None, v_fz=None, k_fz_smax=None,
                              k_fz_shift=None, v_fz_smax=None, v_fz_shift=None,
                              window: int = 0, interpret: bool = False):
-    """q: (B, H, hd) single-token queries; k_pages/v_pages: (P+1, page, KV,
-    hd) uint8 codes (``fmt`` quantized) or bf16 values; k/v_smax: (P+1,) f32;
-    k/v_shift: (P+1, KV) int32 (ignored for bf16 pages);
-    page_table: (B, PP) int32; kv_lens: (B,) valid token counts; ``window``:
+    """q: (B, H, hd) single-token queries; k_pages/v_pages: the stacked
+    (L, P+1, page, KV, hd) uint8 codes (``fmt`` quantized) or bf16 values;
+    k/v_smax: (L, P+1) f32; k/v_shift: (L, P+1, KV) int32 (ignored for bf16
+    pages); page_table: (B, PP) int32; kv_lens: (B,) valid token counts;
+    ``layer``: the layer to read, a scalar-prefetch operand, so the pages
+    of a lane-dense store are DMA'd straight out of the stacked store (a
+    narrower one is sliced to the layer, ``_code_operand``; so are the
+    per-page scales, a few KB a layer: smax for SMEM, the shifts for their
+    (KV, 1) blocks, whose tiled layout is a copy); ``window``:
     sliding-window size (0 = full history). ``fmt``/``frozen`` accept a
     PageFormat or a format name (coerced via ``page_format`` — unknown names
     fail fast with the allowed set). With ``frozen`` set the ``*_fz``
-    operands carry the packed FP4 region ((F+1, page, KV, ceil(hd/2)) codes
-    + its own scales; row F is the dummy clamped gathers land on) and table
-    entries >= P+1 select it per page. Returns (B, H, dv) f32. GQA head
-    repetition is internal: each grid step reads one whole page (every KV
-    head) once and serves all g = H/KV query heads of each group.
+    operands carry the stacked packed FP4 region ((L, F+1, page, KV,
+    ceil(hd/2)) codes + its own scales; row F is the dummy clamped gathers
+    land on) and table entries >= P+1 select it per page. Returns (B, H, dv)
+    f32. GQA head repetition is internal: each grid step reads one whole
+    page (every KV head) once and serves all g = H/KV query heads of each
+    group.
     """
     fmt = page_format(fmt)
     frozen = page_format(frozen) if frozen is not None else None
@@ -218,21 +247,22 @@ def paged_decode_attn_pallas(q, k_pages, v_pages, k_smax, k_shift, v_smax,
         "a frozen region requires quantized active pages"
     classes = _page_classes(fmt, frozen)
     b, h, hd = q.shape
-    p1, page, kv, _ = k_pages.shape
+    _, p1, page, kv, _ = k_pages.shape
     pp = page_table.shape[1]
     g = h // kv
     # (B, KV, g, hd) head order -> g-major blocks with KV on sublanes
     qg = jnp.swapaxes(q.reshape(b, kv, g, hd), 1, 2)
-    nfz = 0 if k_fz is None else k_fz.shape[0] - 1
+    nfz = 0 if k_fz is None else k_fz.shape[1] - 1
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
-    def page_map(bi, ji, pt, *_s):
+    def page_map(bi, ji, pt, _lens, lyr, *_s):
         pid = pt[bi, ji]
         if frozen is not None:  # frozen ids clamp to the null page
             pid = jnp.minimum(pid, p1 - 1)
-        return (pid, 0, 0, 0)
+        return (lyr[0], pid, 0, 0, 0)
 
-    def fz_page_map(bi, ji, pt, *_s):
-        return (jnp.clip(pt[bi, ji] - p1, 0, nfz), 0, 0, 0)
+    def fz_page_map(bi, ji, pt, _lens, lyr, *_s):
+        return (lyr[0], jnp.clip(pt[bi, ji] - p1, 0, nfz), 0, 0, 0)
 
     def q_map(bi, ji, *_s):
         return (bi, 0, 0, 0)
@@ -241,11 +271,11 @@ def paged_decode_attn_pallas(q, k_pages, v_pages, k_smax, k_shift, v_smax,
     if frozen is not None:
         stores.append((k_fz, v_fz, k_fz_smax, v_fz_smax, k_fz_shift,
                        v_fz_shift, fz_page_map))
-    scalars = [page_table, kv_lens]
+    scalars = [page_table, kv_lens, layer]
     tensors, in_specs = [], []
     for f, st in zip(classes, stores):
         if f.quantized:
-            scalars += [st[2], st[3]]
+            scalars += [st[2][layer[0]], st[3][layer[0]]]
     if any(not f.packed for f in classes):
         tensors.append(qg)
         in_specs.append(pl.BlockSpec((1, g, kv, hd), q_map))
@@ -256,14 +286,17 @@ def paged_decode_attn_pallas(q, k_pages, v_pages, k_smax, k_shift, v_smax,
         in_specs += [pl.BlockSpec((1, g, kv, width), q_map)] * 2
     out_shape, out_specs, acc_shapes = [], [], []
     for f, (kp, vp, _, _, ksh, vsh, pmap) in zip(classes, stores):
-        tensors += [kp, vp]
-        in_specs += [pl.BlockSpec((1, page, kv, kp.shape[-1]), pmap),
-                     pl.BlockSpec((1, page, kv, vp.shape[-1]), pmap)]
+        for st in (kp, vp):
+            st, m = _code_operand(st, layer, pmap)
+            tensors.append(st)
+            in_specs.append(pl.BlockSpec(
+                (pl.Squeezed(), 1, page, kv, st.shape[-1]), m))
         if f.quantized:
             # the (KV, 1) shift column of the gathered page: a trailing unit
-            # dim keeps the block's two minor dims whole-array
-            smap = lambda *ix, pmap=pmap: pmap(*ix)[:3]
-            tensors += [ksh[..., None], vsh[..., None]]
+            # dim keeps the block's two minor dims whole-array. It changes
+            # the tiled layout, so it is a copy: of the layer's slice only
+            smap = lambda *ix, pmap=pmap: pmap(*ix)[1:4]
+            tensors += [ksh[layer[0], ..., None], vsh[layer[0], ..., None]]
             in_specs += [pl.BlockSpec((1, kv, 1), smap)] * 2
         for _ in range(2 if f.packed else 1):
             out_shape.append(jax.ShapeDtypeStruct((b, g, kv, vp.shape[-1]),
@@ -312,7 +345,7 @@ def _mla_kernel(*refs, page, pp, scale, classes, base, nfz):
     accumulate their context apart."""
     refs = list(refs)
     take = lambda n: [refs.pop(0) for _ in range(n)]
-    pt_ref, len_ref = take(2)
+    pt_ref, len_ref, _layer_ref = take(3)
     scal = [take(4) if f.quantized else None for f in classes]
     q_nat = take(2) if any(not f.packed for f in classes) else None
     q_pair = take(4) if any(f.packed for f in classes) else None
@@ -394,7 +427,7 @@ def _mla_kernel(*refs, page, pp, scale, classes, base, nfz):
                                              "interpret"))
 def paged_mla_decode_attn_pallas(q_lat, q_rope, ckv_pages, krope_pages,
                                  ckv_smax, ckv_shift, krope_smax, krope_shift,
-                                 page_table, kv_lens, scale,
+                                 page_table, kv_lens, layer, scale,
                                  fmt=None, frozen=None,
                                  ckv_fz=None, krope_fz=None, ckv_fz_smax=None,
                                  ckv_fz_shift=None, krope_fz_smax=None,
@@ -403,16 +436,20 @@ def paged_mla_decode_attn_pallas(q_lat, q_rope, ckv_pages, krope_pages,
     """MLA absorbed decode over latent pages (flash-decoding dataflow).
 
     q_lat: (B, H, r) queries absorbed into the latent space; q_rope:
-    (B, H, dr) rope-space queries; ckv_pages: (P+1, page, r) and
-    krope_pages: (P+1, page, dr) uint8 codes (``fmt`` quantized) or bf16;
-    c/r smax: (P+1,) f32; c/r shift: (P+1, 1) int32 (single scale "head");
-    page_table: (B, PP) int32; kv_lens: (B,); ``scale``: softmax scale
+    (B, H, dr) rope-space queries; ckv_pages: the stacked (L, P+1, page, r)
+    and krope_pages: (L, P+1, page, dr) uint8 codes (``fmt`` quantized) or
+    bf16; c/r smax: (L, P+1) f32; c/r shift: (L, P+1, 1) int32 (single
+    scale "head"); page_table: (B, PP) int32; kv_lens: (B,); ``layer``: the
+    layer to read, a scalar-prefetch operand that the page index maps
+    address the stacked stores with (``krope``, narrower than a lane tile,
+    is sliced to the layer, ``_code_operand``; so are the per-page scales,
+    a few KB a layer, for SMEM); ``scale``: softmax scale
     (1/sqrt(qk_nope + qk_rope)). ``fmt``/``frozen`` are PageFormats (or
     names — ``page_format`` coercion fails fast on unknowns); with
-    ``frozen`` set the ``*_fz`` operands carry the packed FP4 latent region
-    ((F+1, page, ceil(d/2)) codes + scales) and table entries >= P+1 select
-    it per page. Returns the latent context (B, H, r) f32 — the caller
-    applies the absorbed v_up projection.
+    ``frozen`` set the ``*_fz`` operands carry the stacked packed FP4
+    latent region ((L, F+1, page, ceil(d/2)) codes + scales) and table
+    entries >= P+1 select it per page. Returns the latent context (B, H, r)
+    f32 — the caller applies the absorbed v_up projection.
 
     KV is a single head: every query head scores the same k =
     concat(ckv, krope) page block and v is the ckv view, so the grid is
@@ -426,7 +463,7 @@ def paged_mla_decode_attn_pallas(q_lat, q_rope, ckv_pages, krope_pages,
     classes = _page_classes(fmt, frozen)
     b, h, r = q_lat.shape
     dr = q_rope.shape[-1]
-    p1, page, _ = ckv_pages.shape
+    _, p1, page, _ = ckv_pages.shape
     pp = page_table.shape[1]
     bq = -(-bq // 8) * 8  # a sublane multiple: the head block is tile-legal
     hb = -(-h // bq)
@@ -436,16 +473,17 @@ def paged_mla_decode_attn_pallas(q_lat, q_rope, ckv_pages, krope_pages,
         q_rope = jnp.pad(q_rope, ((0, 0), (0, pad), (0, 0)))
     ql = q_lat.reshape(b, hb, bq, r)
     qr = q_rope.reshape(b, hb, bq, dr)
-    nfz = 0 if ckv_fz is None else ckv_fz.shape[0] - 1
+    nfz = 0 if ckv_fz is None else ckv_fz.shape[1] - 1
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
-    def page_map(bi, hi, ji, pt, *_s):
+    def page_map(bi, hi, ji, pt, _lens, lyr, *_s):
         pid = pt[bi, ji]
         if frozen is not None:  # frozen ids clamp to the null page
             pid = jnp.minimum(pid, p1 - 1)
-        return (pid, 0, 0)
+        return (lyr[0], pid, 0, 0)
 
-    def fz_page_map(bi, hi, ji, pt, *_s):
-        return (jnp.clip(pt[bi, ji] - p1, 0, nfz), 0, 0)
+    def fz_page_map(bi, hi, ji, pt, _lens, lyr, *_s):
+        return (lyr[0], jnp.clip(pt[bi, ji] - p1, 0, nfz), 0, 0)
 
     def q_map(bi, hi, ji, *_s):
         return (bi, hi, 0, 0)
@@ -455,10 +493,10 @@ def paged_mla_decode_attn_pallas(q_lat, q_rope, ckv_pages, krope_pages,
     if frozen is not None:
         stores.append((ckv_fz, krope_fz, ckv_fz_smax, ckv_fz_shift,
                        krope_fz_smax, krope_fz_shift, fz_page_map))
-    scalars = [page_table, kv_lens]
+    scalars = [page_table, kv_lens, layer]
     for f, st in zip(classes, stores):
-        if f.quantized:  # per-page scalars, flat in SMEM
-            scalars += [a.reshape(-1) for a in st[2:6]]
+        if f.quantized:  # the layer's per-page scalars, flat in SMEM
+            scalars += [a[layer[0]].reshape(-1) for a in st[2:6]]
     qspec = lambda d: pl.BlockSpec((1, 1, bq, d), q_map)
     tensors, in_specs = [], []
     if any(not f.packed for f in classes):
@@ -471,9 +509,11 @@ def paged_mla_decode_attn_pallas(q_lat, q_rope, ckv_pages, krope_pages,
         in_specs += [qspec(cw)] * 2 + [qspec(rw)] * 2
     out_shape, out_specs, acc_shapes = [], [], []
     for f, (cp, rp, *_, pmap) in zip(classes, stores):
-        tensors += [cp, rp]
-        in_specs += [pl.BlockSpec((1, page, cp.shape[-1]), pmap),
-                     pl.BlockSpec((1, page, rp.shape[-1]), pmap)]
+        for st in (cp, rp):
+            st, m = _code_operand(st, layer, pmap)
+            tensors.append(st)
+            in_specs.append(pl.BlockSpec(
+                (pl.Squeezed(), 1, page, st.shape[-1]), m))
         for _ in range(2 if f.packed else 1):
             out_shape.append(jax.ShapeDtypeStruct((b, hb, bq, cp.shape[-1]),
                                                   jnp.float32))

@@ -184,59 +184,63 @@ def w4a8_matmul_batched(x, w, transpose_w: bool = False,
     )
 
 
-def _layer_formats(pool_layer, key: str):
-    """Derive the (active, frozen) PageFormats from a pool slice's leaves —
+def _layer_formats(pool, key: str):
+    """Derive the (active, frozen) PageFormats from a pool's leaves —
     dtype picks quantized vs bf16, the zero-size ``_fp4`` marker picks
     packed FP4 over FP8, and a ``<key>_fz`` leaf announces the dedicated
     packed frozen region (mirrors runtime.kv_cache.pool_format /
     frozen_format without importing the runtime layer)."""
     from .common import page_format
 
-    leaf = pool_layer[key]
+    leaf = pool[key]
     if leaf.dtype != jnp.uint8:
         name = None
     else:
-        name = "fp4_e2m1" if "_fp4" in pool_layer else "fp8_e4m3"
-    frozen = ("fp4_e2m1" if key + "_fz" in pool_layer else None)
+        name = "fp4_e2m1" if "_fp4" in pool else "fp8_e4m3"
+    frozen = ("fp4_e2m1" if key + "_fz" in pool else None)
     return page_format(name), page_format(frozen) if frozen else None
 
 
-def _fz_operands(pool_layer, names):
+def _fz_operands(pool, names):
     """The frozen-region operand dict for the kernel/oracle call: the
     ``*_fz`` leaves when present, else all-None (the wrappers skip the
     frozen operand block entirely)."""
     out = {}
     for name in names:
         for suffix in ("_fz", "_fz_smax", "_fz_shift"):
-            out[name + suffix] = pool_layer.get(name + suffix)
+            out[name + suffix] = pool.get(name + suffix)
     return out
 
 
 def _pool_shard_spec(name: str, leaf, msize: int):
-    """PartitionSpec for one per-layer pool-slice leaf under head-sharded
-    decode: 4-D ``(pages, page, KV, hd)`` code stores shard their head dim,
-    per-(page, head) ``*_shift`` scales co-shard with them, and everything
-    else (per-page smax, MLA latents, zero-size format markers) replicates.
+    """PartitionSpec for one stacked pool leaf under head-sharded decode:
+    5-D ``(L, pages, page, KV, hd)`` code stores shard their head dim,
+    per-(page, head) ``*_shift`` scales ``(L, pages, KV)`` co-shard with
+    them, and everything else (per-page smax, MLA latents, zero-size format
+    markers) replicates — the placement the serving engine gives the pool
+    (launch.sharding), restated here so kernels need not import it.
     """
     from jax.sharding import PartitionSpec as P
 
-    if leaf.ndim == 4 and leaf.size and leaf.shape[2] % msize == 0:
-        return P(None, None, "model", None)
-    if leaf.ndim == 2 and name.endswith("_shift") and \
-            leaf.shape[1] % msize == 0:
-        return P(None, "model")
+    if leaf.ndim == 5 and leaf.size and leaf.shape[3] % msize == 0:
+        return P(None, None, None, "model", None)
+    if leaf.ndim == 3 and name.endswith("_shift") and \
+            leaf.shape[2] % msize == 0:
+        return P(None, None, "model")
     return P()
 
 
-def paged_decode_attn(q, pool_layer, page_table, kv_lens, window: int = 0):
-    """Paged decode attention over one layer's quantized KV pool slice.
+def paged_decode_attn(q, pool, layer, page_table, kv_lens, window: int = 0):
+    """Paged decode attention over one layer of a stacked quantized KV pool.
 
-    q: (B, H, hd) single-token queries; pool_layer: one layer of a
-    runtime.kv_cache GQA pool ({'k', 'v'} + fp8 scale leaves, plus the
-    packed ``*_fz`` frozen-region leaves in a mixed-precision pool);
-    page_table: (B, PP) int32 — entries >= P+1 are frozen logical ids;
-    kv_lens: (B,) int32 valid token counts; ``window``: sliding-window size
-    (0 = full history). Returns (B, H, dv) f32.
+    q: (B, H, hd) single-token queries; pool: a whole runtime.kv_cache GQA
+    pool ({'k', 'v'} + fp8 scale leaves, leading L dim, plus the packed
+    ``*_fz`` frozen-region leaves in a mixed-precision pool); ``layer``:
+    the layer to attend over — the kernel reads that layer's pages straight
+    out of the stacked stores; page_table: (B, PP) int32 — entries >= P+1
+    are frozen logical ids; kv_lens: (B,) int32 valid token counts;
+    ``window``: sliding-window size (0 = full history). Returns (B, H, dv)
+    f32.
 
     With a serving mesh installed (``set_serving_mesh``) and the KV-head dim
     divisible by the 'model' axis, the whole dataflow runs under
@@ -245,61 +249,63 @@ def paged_decode_attn(q, pool_layer, page_table, kv_lens, window: int = 0):
     head-sharded, no collectives, outputs stay head-sharded. Non-divisible
     head counts fall through to the unsharded call (GSPMD places it).
     """
+    layer = jnp.asarray(layer, jnp.int32)
     mesh = _SERVING_MESH[0]
     if mesh is not None:
         msize = mesh.shape.get("model", 1)
-        kvh, h = pool_layer["k"].shape[2], q.shape[1]
+        kvh, h = pool["k"].shape[3], q.shape[1]
         if msize > 1 and kvh % msize == 0 and h % msize == 0:
             from jax.sharding import PartitionSpec as P
 
             specs = {n: _pool_shard_spec(n, l, msize)
-                     for n, l in pool_layer.items()}
+                     for n, l in pool.items()}
             hspec = P(None, "model", None)
             fn = jax.shard_map(
-                lambda qv, pl, pt, kl: _paged_decode_attn_impl(
-                    qv, pl, pt, kl, window=window),
-                mesh=mesh, in_specs=(hspec, specs, P(), P()),
+                lambda qv, pl, li, pt, kl: _paged_decode_attn_impl(
+                    qv, pl, li, pt, kl, window=window),
+                mesh=mesh, in_specs=(hspec, specs, P(), P(), P()),
                 out_specs=hspec, check_vma=False)
-            return fn(q, pool_layer, page_table, kv_lens)
-    return _paged_decode_attn_impl(q, pool_layer, page_table, kv_lens,
+            return fn(q, pool, layer, page_table, kv_lens)
+    return _paged_decode_attn_impl(q, pool, layer, page_table, kv_lens,
                                    window=window)
 
 
-def _paged_decode_attn_impl(q, pool_layer, page_table, kv_lens,
+def _paged_decode_attn_impl(q, pool, layer, page_table, kv_lens,
                             window: int = 0):
     """Single-shard paged decode attention (backend dispatch unchanged —
     this is exactly the pre-mesh dataflow; under shard_map every shape
     below is the per-shard local shape)."""
-    kp, vp = pool_layer["k"], pool_layer["v"]
-    fmt, frozen = _layer_formats(pool_layer, "k")
+    kp, vp = pool["k"], pool["v"]
+    fmt, frozen = _layer_formats(pool, "k")
     if fmt.quantized:
-        ksm, ksh = pool_layer["k_smax"], pool_layer["k_shift"]
-        vsm, vsh = pool_layer["v_smax"], pool_layer["v_shift"]
+        ksm, ksh = pool["k_smax"], pool["k_shift"]
+        vsm, vsh = pool["v_smax"], pool["v_shift"]
     else:  # dummies keep the kernel operand list static across formats
-        ksm = vsm = jnp.zeros((1,), jnp.float32)
-        ksh = vsh = jnp.zeros((1, 1), jnp.int32)
-    fz = _fz_operands(pool_layer, ("k", "v"))
+        ksm = vsm = jnp.zeros((1, 1), jnp.float32)
+        ksh = vsh = jnp.zeros((1, 1, 1), jnp.int32)
+    fz = _fz_operands(pool, ("k", "v"))
     if _BACKEND.startswith("pallas"):
         from .decode_attn import paged_decode_attn_pallas
 
         return paged_decode_attn_pallas(
-            q, kp, vp, ksm, ksh, vsm, vsh, page_table, kv_lens,
+            q, kp, vp, ksm, ksh, vsm, vsh, page_table, kv_lens, layer,
             fmt=fmt, frozen=frozen, window=window,
             interpret=interpret_mode(), **fz,
         )
     return _ref.paged_decode_attn_ref(
-        q, kp, vp, ksm, ksh, vsm, vsh, page_table, kv_lens, fmt=fmt,
+        q, kp, vp, ksm, ksh, vsm, vsh, page_table, kv_lens, layer, fmt=fmt,
         window=window, frozen=frozen, **fz,
     )
 
 
-def paged_mla_decode_attn(q_lat, q_rope, pool_layer, page_table, kv_lens,
+def paged_mla_decode_attn(q_lat, q_rope, pool, layer, page_table, kv_lens,
                           scale: float):
-    """MLA absorbed decode over one layer's latent page pool slice.
+    """MLA absorbed decode over one layer of a stacked latent page pool.
 
     q_lat: (B, H, r) queries absorbed into the latent space; q_rope:
-    (B, H, dr); pool_layer: one layer of a runtime.kv_cache MLA pool
-    ({'ckv', 'krope'} + fp8 scale leaves); page_table: (B, PP) int32;
+    (B, H, dr); pool: a whole runtime.kv_cache MLA pool ({'ckv', 'krope'}
+    + fp8 scale leaves, leading L dim); ``layer``: the layer to attend
+    over, read in place from the stacked stores; page_table: (B, PP) int32;
     kv_lens: (B,) int32; ``scale``: softmax scale (1/sqrt(nope + rope
     dims)). Returns the latent context (B, H, r) f32 — KV is one head,
     k = concat(ckv, krope), v = the ckv view.
@@ -313,43 +319,44 @@ def paged_mla_decode_attn(q_lat, q_rope, pool_layer, page_table, kv_lens,
     runs its head group against the full latent pages, so the contraction
     is local and the (B, H, r) context comes back head-sharded.
     """
+    layer = jnp.asarray(layer, jnp.int32)
     mesh = _SERVING_MESH[0]
     if mesh is not None:
         msize = mesh.shape.get("model", 1)
         if msize > 1 and q_lat.shape[1] % msize == 0:
             from jax.sharding import PartitionSpec as P
 
-            specs = {n: P() for n in pool_layer}  # latents: no head axis
+            specs = {n: P() for n in pool}  # latents: no head axis
             hspec = P(None, "model", None)
             fn = jax.shard_map(
-                lambda ql, qr, pl, pt, kl: _paged_mla_decode_attn_impl(
-                    ql, qr, pl, pt, kl, scale=scale),
-                mesh=mesh, in_specs=(hspec, hspec, specs, P(), P()),
+                lambda ql, qr, pl, li, pt, kl: _paged_mla_decode_attn_impl(
+                    ql, qr, pl, li, pt, kl, scale=scale),
+                mesh=mesh, in_specs=(hspec, hspec, specs, P(), P(), P()),
                 out_specs=hspec, check_vma=False)
-            return fn(q_lat, q_rope, pool_layer, page_table, kv_lens)
-    return _paged_mla_decode_attn_impl(q_lat, q_rope, pool_layer, page_table,
-                                       kv_lens, scale=scale)
+            return fn(q_lat, q_rope, pool, layer, page_table, kv_lens)
+    return _paged_mla_decode_attn_impl(q_lat, q_rope, pool, layer,
+                                       page_table, kv_lens, scale=scale)
 
 
-def _paged_mla_decode_attn_impl(q_lat, q_rope, pool_layer, page_table,
+def _paged_mla_decode_attn_impl(q_lat, q_rope, pool, layer, page_table,
                                 kv_lens, scale: float):
     """Single-shard MLA absorbed decode (the pre-mesh dataflow; under
     shard_map the head dim below is the per-shard local head count)."""
-    cp, rp = pool_layer["ckv"], pool_layer["krope"]
-    fmt, frozen = _layer_formats(pool_layer, "ckv")
+    cp, rp = pool["ckv"], pool["krope"]
+    fmt, frozen = _layer_formats(pool, "ckv")
     if fmt.quantized:
-        csm, csh = pool_layer["ckv_smax"], pool_layer["ckv_shift"]
-        rsm, rsh = pool_layer["krope_smax"], pool_layer["krope_shift"]
+        csm, csh = pool["ckv_smax"], pool["ckv_shift"]
+        rsm, rsh = pool["krope_smax"], pool["krope_shift"]
     else:  # dummies keep the kernel operand list static across formats
-        csm = rsm = jnp.zeros((1,), jnp.float32)
-        csh = rsh = jnp.zeros((1, 1), jnp.int32)
-    fz = _fz_operands(pool_layer, ("ckv", "krope"))
+        csm = rsm = jnp.zeros((1, 1), jnp.float32)
+        csh = rsh = jnp.zeros((1, 1, 1), jnp.int32)
+    fz = _fz_operands(pool, ("ckv", "krope"))
     if _BACKEND.startswith("pallas"):
         from .autotune import best_block_sizes
         from .decode_attn import paged_mla_decode_attn_pallas
 
         b, h, r = q_lat.shape
-        page = cp.shape[1]
+        page = cp.shape[2]
         # same autotune kind as GQA decode: bm is the query-head block,
         # bn the page size; the latent contraction dim is r + dr
         bq, _ = best_block_sizes(
@@ -359,12 +366,12 @@ def _paged_mla_decode_attn_impl(q_lat, q_rope, pool_layer, page_table,
         )
         return paged_mla_decode_attn_pallas(
             q_lat, q_rope, cp, rp, csm, csh, rsm, rsh, page_table, kv_lens,
-            scale, fmt=fmt, frozen=frozen, bq=bq,
+            layer, scale, fmt=fmt, frozen=frozen, bq=bq,
             interpret=interpret_mode(), **fz,
         )
     return _ref.paged_mla_decode_attn_ref(
         q_lat, q_rope, cp, rp, csm, csh, rsm, rsh, page_table, kv_lens,
-        scale, fmt=fmt, frozen=frozen, **fz,
+        layer, scale, fmt=fmt, frozen=frozen, **fz,
     )
 
 

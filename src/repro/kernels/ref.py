@@ -132,16 +132,18 @@ def w4a8_batched_matmul_ref(x, codes, scale, lorc_a=None, lorc_b=None,
 
 
 def paged_decode_attn_ref(q, k_pages, v_pages, k_smax, k_shift, v_smax,
-                          v_shift, page_table, kv_lens, fmt=None,
+                          v_shift, page_table, kv_lens, layer, fmt=None,
                           window: int = 0, frozen=None,
                           k_fz=None, v_fz=None, k_fz_smax=None,
                           k_fz_shift=None, v_fz_smax=None, v_fz_shift=None):
     """Oracle for the paged decode-attention kernel.
 
-    q: (B, H, hd); k_pages/v_pages: (P+1, page, KV, hd) uint8 codes
-    (``fmt`` quantized) or bf16 values (``fmt`` None); k/v_smax: (P+1,) f32
-    per-page full-precision scales; k/v_shift: (P+1, KV) int32 M2 exponent
-    shifts; page_table: (B, PP) int32; kv_lens: (B,) valid token counts.
+    q: (B, H, hd); k_pages/v_pages: (L, P+1, page, KV, hd) uint8 codes
+    (``fmt`` quantized) or bf16 values (``fmt`` None); k/v_smax: (L, P+1)
+    f32 per-page full-precision scales; k/v_shift: (L, P+1, KV) int32 M2
+    exponent shifts; page_table: (B, PP) int32; kv_lens: (B,) valid token
+    counts; ``layer``: the layer of the stacked stores to read (only the
+    table's pages of it are gathered).
     ``fmt``/``frozen`` take a PageFormat or format name (coerced via
     ``page_format``); with ``frozen`` set the ``*_fz`` operands carry the
     packed FP4 region and table entries >= P+1 are frozen logical ids —
@@ -159,7 +161,7 @@ def paged_decode_attn_ref(q, k_pages, v_pages, k_smax, k_shift, v_smax,
     fmt = page_format(fmt)
     frozen = page_format(frozen) if frozen is not None else None
     b, h, hd = q.shape
-    base, page, kv, _ = k_pages.shape
+    _, base, page, kv, _ = k_pages.shape
     dv = v_pages.shape[-1]
     pp = page_table.shape[1]
     g = h // kv
@@ -167,18 +169,18 @@ def paged_decode_attn_ref(q, k_pages, v_pages, k_smax, k_shift, v_smax,
     def dq(pages, smax, shift, fpages, fsmax, fshift):
         apt = (jnp.minimum(page_table, base - 1) if frozen is not None
                else page_table)
-        gathered = pages[apt]  # (B, PP, page, KV, d)
+        gathered = pages[layer, apt]  # (B, PP, page, KV, d)
         if not fmt.quantized:
             return gathered.astype(jnp.float32).reshape(b, pp * page, kv, -1)
         d = pages.shape[-1] * (2 if fmt.packed else 1)
-        vals = fmt.decode(gathered, shift[apt][:, :, None, :, None], d)
-        vals = vals * smax[apt][:, :, None, None, None]
+        vals = fmt.decode(gathered, shift[layer, apt][:, :, None, :, None], d)
+        vals = vals * smax[layer, apt][:, :, None, None, None]
         if frozen is not None:
-            fpt = jnp.clip(page_table - base, 0, fpages.shape[0] - 1)
-            fvals = frozen.decode(fpages[fpt],
-                                  fshift[fpt][:, :, None, :, None],
+            fpt = jnp.clip(page_table - base, 0, fpages.shape[1] - 1)
+            fvals = frozen.decode(fpages[layer, fpt],
+                                  fshift[layer, fpt][:, :, None, :, None],
                                   pages.shape[-1])
-            fvals = fvals * fsmax[fpt][:, :, None, None, None]
+            fvals = fvals * fsmax[layer, fpt][:, :, None, None, None]
             mask = (page_table >= base)[:, :, None, None, None]
             vals = jnp.where(mask, fvals, vals)
         return vals.reshape(b, pp * page, kv, -1)
@@ -201,17 +203,18 @@ def paged_decode_attn_ref(q, k_pages, v_pages, k_smax, k_shift, v_smax,
 
 def paged_mla_decode_attn_ref(q_lat, q_rope, ckv_pages, krope_pages,
                               ckv_smax, ckv_shift, krope_smax, krope_shift,
-                              page_table, kv_lens, scale, fmt=None,
+                              page_table, kv_lens, layer, scale, fmt=None,
                               frozen=None, ckv_fz=None, krope_fz=None,
                               ckv_fz_smax=None, ckv_fz_shift=None,
                               krope_fz_smax=None, krope_fz_shift=None):
     """Oracle for the MLA latent decode kernel.
 
     q_lat: (B, H, r) absorbed queries; q_rope: (B, H, dr); ckv_pages:
-    (P+1, page, r) / krope_pages: (P+1, page, dr) uint8 codes (``fmt``
-    quantized) or bf16; c/r smax: (P+1,) f32; c/r shift: (P+1, 1)
-    int32 (the latent has a single scale "head"); page_table: (B, PP);
-    kv_lens: (B,). ``fmt``/``frozen`` as in ``paged_decode_attn_ref``; the
+    (L, P+1, page, r) / krope_pages: (L, P+1, page, dr) uint8 codes
+    (``fmt`` quantized) or bf16; c/r smax: (L, P+1) f32; c/r shift:
+    (L, P+1, 1) int32 (the latent has a single scale "head"); page_table:
+    (B, PP); kv_lens: (B,); ``layer``: the layer of the stacked stores to
+    read. ``fmt``/``frozen`` as in ``paged_decode_attn_ref``; the
     ``*_fz`` operands carry the packed FP4 latent region. Scores are the
     k = concat(ckv, krope) contraction, v is the ckv view. Returns the
     latent context (B, H, r) f32.
@@ -219,23 +222,24 @@ def paged_mla_decode_attn_ref(q_lat, q_rope, ckv_pages, krope_pages,
     fmt = page_format(fmt)
     frozen = page_format(frozen) if frozen is not None else None
     b, h, r = q_lat.shape
-    base, page, _ = ckv_pages.shape
+    _, base, page, _ = ckv_pages.shape
     pp = page_table.shape[1]
 
     def dq(pages, smax, shift, fpages, fsmax, fshift):
         apt = (jnp.minimum(page_table, base - 1) if frozen is not None
                else page_table)
-        gathered = pages[apt]  # (B, PP, page, d)
+        gathered = pages[layer, apt]  # (B, PP, page, d)
         if not fmt.quantized:
             return gathered.astype(jnp.float32).reshape(b, pp * page, -1)
         d = pages.shape[-1] * (2 if fmt.packed else 1)
-        vals = fmt.decode(gathered, shift[apt][..., None], d)
-        vals = vals * smax[apt][:, :, None, None]
+        vals = fmt.decode(gathered, shift[layer, apt][..., None], d)
+        vals = vals * smax[layer, apt][:, :, None, None]
         if frozen is not None:
-            fpt = jnp.clip(page_table - base, 0, fpages.shape[0] - 1)
-            fvals = frozen.decode(fpages[fpt], fshift[fpt][..., None],
+            fpt = jnp.clip(page_table - base, 0, fpages.shape[1] - 1)
+            fvals = frozen.decode(fpages[layer, fpt],
+                                  fshift[layer, fpt][..., None],
                                   pages.shape[-1])
-            fvals = fvals * fsmax[fpt][:, :, None, None]
+            fvals = fvals * fsmax[layer, fpt][:, :, None, None]
             mask = (page_table >= base)[:, :, None, None]
             vals = jnp.where(mask, fvals, vals)
         return vals.reshape(b, pp * page, -1)

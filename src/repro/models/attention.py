@@ -144,12 +144,13 @@ def _sdpa_chunked(q, k, v, causal, window, q_chunk, kv_chunk, q_offset=0):
     return outs[:, :s]
 
 
-def _paged_chunk_attn(q, k, v, pool_layer, state, g: int, window: int):
-    """Attention for one (batch-1) streaming-prefill chunk over the paged
-    pool: gathered history pages + the chunk's own exact K/V inline (the
-    chunk never round-trips the FP8 grid early). Shared by the pure chunk
-    branch and the mixed step's prefill rows — the mask/gather math must
-    stay identical so the two engines are bit-identical.
+def _paged_chunk_attn(q, k, v, pool, layer, state, g: int, window: int):
+    """Attention for one (batch-1) streaming-prefill chunk over layer
+    ``layer`` of the stacked paged pool: gathered history pages + the
+    chunk's own exact K/V inline (the chunk never round-trips the FP8 grid
+    early). Shared by the pure chunk branch and the mixed step's prefill
+    rows — the mask/gather math must stay identical so the two engines are
+    bit-identical.
 
     q/k/v: (1, S, ·, hd) — the chunk's queries and fresh K/V, rope applied.
     ``state`` is the batch-1 chunk PagedState (lengths[0] = chunk start,
@@ -158,7 +159,7 @@ def _paged_chunk_attn(q, k, v, pool_layer, state, g: int, window: int):
     true history is read from pages.
     """
     s = q.shape[1]
-    hist, hist_len = gather_history(pool_layer, state, s)
+    hist, hist_len = gather_history(pool, layer, state, s)
     start = state.lengths[0]
     kc, vc = k, v
     if hist_len:
@@ -193,8 +194,13 @@ def attention(
     n_heads=None,
     n_kv=None,
     head_dim=None,
+    layer=None,
 ):
     """Returns (out, new_kv_cache_or_None). ``positions``: (S,) int32.
+
+    Paged (``cache_index`` a PagedState): ``kv_cache`` is the whole stacked
+    page pool and ``layer`` the layer this call reads and writes; the
+    returned cache is the same stack with only the touched pages changed.
 
     Modes (decided statically from shapes):
       * train forward: kv_cache None — chunked/full attention over x.
@@ -257,16 +263,17 @@ def attention(
             k_dec = jnp.swapaxes(k[:, :nd], 0, 1)  # (nd, 1, KV, hd)
             v_dec = jnp.swapaxes(v[:, :nd], 0, 1)
             with jax.named_scope("kv_write"):
-                cache1 = append_paged(kv_cache, {"k": k_dec, "v": v_dec}, dec)
+                cache1 = append_paged(kv_cache, layer,
+                                      {"k": k_dec, "v": v_dec}, dec)
                 new_cache = append_prefill_chunk(
-                    cache1, {"k": k[:, nd:], "v": v[:, nd:]}, pre)
+                    cache1, layer, {"k": k[:, nd:], "v": v[:, nd:]}, pre)
             q_dec = jnp.swapaxes(q[:, :nd], 0, 1)
             o_dec = ops.paged_decode_attn(
-                q_dec[:, 0], new_cache, dec.page_table, dec.lengths + 1,
-                window=cfg.window,
+                q_dec[:, 0], new_cache, layer, dec.page_table,
+                dec.lengths + 1, window=cfg.window,
             )
             o_pre = _paged_chunk_attn(q[:, nd:], k[:, nd:], v[:, nd:],
-                                      new_cache, pre, g, cfg.window)
+                                      new_cache, layer, pre, g, cfg.window)
             o = jnp.concatenate(
                 [jnp.swapaxes(o_dec[:, None], 0, 1).astype(x.dtype),
                  o_pre.astype(x.dtype)], axis=1)  # (1, nd + S, H, hd)
@@ -284,10 +291,10 @@ def attention(
             from repro.kernels import ops
 
             with jax.named_scope("kv_write"):
-                new_cache = append_paged(kv_cache, {"k": k, "v": v},
+                new_cache = append_paged(kv_cache, layer, {"k": k, "v": v},
                                          cache_index)
             o = ops.paged_decode_attn(
-                q[:, 0], new_cache, cache_index.page_table,
+                q[:, 0], new_cache, layer, cache_index.page_table,
                 cache_index.lengths + 1, window=cfg.window,
             )
             o = o[:, None].astype(x.dtype)  # (B, 1, H, hd)
@@ -305,9 +312,10 @@ def attention(
             assert causal, "streaming paged prefill assumes causal decode LMs"
             assert b == 1, "streaming paged prefill is row-wise (batch 1)"
             with jax.named_scope("kv_write"):
-                new_cache = append_prefill_chunk(kv_cache, {"k": k, "v": v},
+                new_cache = append_prefill_chunk(kv_cache, layer,
+                                                 {"k": k, "v": v},
                                                  cache_index)
-            o = _paged_chunk_attn(q, k, v, new_cache, cache_index, g,
+            o = _paged_chunk_attn(q, k, v, new_cache, layer, cache_index, g,
                                   cfg.window)
         o = o.reshape(b, s, h * hd)
         out = linear(p["wo"], quant_act(o, a_fmt), p.get("bo"))
@@ -345,13 +353,14 @@ def attention(
     return out, new_cache
 
 
-def paged_cross_attention(p, x, cfg, positions, cross_layer,
+def paged_cross_attention(p, x, cfg, positions, cross_pool, layer,
                           state: PagedState, a_fmt: Optional[str] = None):
     """Enc-dec decoder cross-attention over *write-once* cross pages.
 
     The encoder ran once at admission and its per-layer K/V was quantized
     into immutable cross pages (``kv_cache.write_cross_pages``); here the
-    decoder only ever reads them. Decode (s == 1) runs the same paged
+    decoder only ever reads them, from layer ``layer`` of the stacked
+    ``cross_pool``. Decode (s == 1) runs the same paged
     flash-decoding kernel as self-attention with ``kv_lens =
     state.enc_lengths`` — cross-attention is non-causal, so the per-row
     length mask *is* the whole mask. Prefill chunks (s > 1, batch 1) gather
@@ -369,14 +378,15 @@ def paged_cross_attention(p, x, cfg, positions, cross_layer,
     if s == 1 and state.chunk_len is None:
         from repro.kernels import ops
 
-        o = ops.paged_decode_attn(q[:, 0], cross_layer, state.cross_table,
-                                  state.enc_lengths, window=0)
+        o = ops.paged_decode_attn(q[:, 0], cross_pool, layer,
+                                  state.cross_table, state.enc_lengths,
+                                  window=0)
         o = o[:, None].astype(x.dtype)  # (B, 1, H, hd)
     else:
         assert b == 1, "streaming paged prefill is row-wise (batch 1)"
         cstate = PagedState(state.cross_table, state.enc_lengths)
-        kf = gather_pages(cross_layer, "k", cstate).astype(x.dtype)
-        vf = gather_pages(cross_layer, "v", cstate).astype(x.dtype)
+        kf = gather_pages(cross_pool, layer, "k", cstate).astype(x.dtype)
+        vf = gather_pages(cross_pool, layer, "v", cstate).astype(x.dtype)
         t = kf.shape[1]
         kf, vf = _repeat_kv(kf, g), _repeat_kv(vf, g)
         ok = jnp.arange(t)[None, :] < state.enc_lengths[:1, None]  # (1, t)

@@ -83,16 +83,18 @@ def encode_cross_pages(params, cfg, frames, caches, cross_table,
     b, t = enc_out.shape[:2]
     kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
 
-    def body(_, xs):
-        p_layer, pool_layer = xs
+    def body(cross, xs):
+        p_layer, layer = xs
         pc = p_layer["mixer"]["cross"]
         ek = linear(pc["wk"], enc_out).reshape(b, t, kv, hd)
         ev = linear(pc["wv"], enc_out, pc.get("bv")).reshape(b, t, kv, hd)
-        return _, write_cross_pages(pool_layer, {"k": ek, "v": ev},
-                                    cross_table)
+        return write_cross_pages(cross, layer, {"k": ek, "v": ev},
+                                 cross_table), None
 
-    cross = caches[0]["cross"]
-    _, new_cross = jax.lax.scan(body, 0, (params["decoder"], cross))
+    # the cross pool rides the carry whole: each layer writes only its
+    # request's pages (see transformer._segment_scan)
+    new_cross, _ = jax.lax.scan(body, caches[0]["cross"],
+                                (params["decoder"], jnp.arange(cfg.n_layers)))
     return [dict(caches[0], cross=new_cross)]
 
 

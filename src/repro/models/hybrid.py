@@ -51,10 +51,11 @@ def build_hybrid(cfg):
     return p
 
 
-def _shared_block(p, x, cfg, positions, kv_cache, cache_index, a_fmt):
+def _shared_block(p, x, cfg, positions, kv_cache, cache_index, a_fmt,
+                  layer=None):
     h, new_kv = attention(
         p["attn"], norm(p["ln1"], x, cfg.norm_kind, cfg.norm_eps), cfg, positions,
-        kv_cache=kv_cache, cache_index=cache_index, a_fmt=a_fmt,
+        kv_cache=kv_cache, cache_index=cache_index, a_fmt=a_fmt, layer=layer,
     )
     x = x + h
     x = x + mlp(p["mlp"], norm(p["ln2"], x, cfg.norm_kind, cfg.norm_eps), cfg, a_fmt=a_fmt)
@@ -78,7 +79,9 @@ def hybrid_forward(
     ``cache_index.slabs`` — and 'shared_kv' is a paged KV pool with the
     invocation index in place of the layer axis ((n_inv, P+1, page, kv,
     hd) + scale leaves), so the shared block's per-invocation caches ride
-    the same page table as any GQA layer stack."""
+    the same page table as any GQA layer stack. The pool rides the scan's
+    carry whole and each invocation addresses it as ``(pool, inv)``, like
+    a GQA segment's layers (``transformer._segment_scan``)."""
     b, s = tokens.shape
     paged = isinstance(cache_index, PagedState)
     if paged:
@@ -103,6 +106,10 @@ def hybrid_forward(
 
             def with_attn(h, shared_kv):
                 inv = li // every
+                if paged:  # the stacked page pool, addressed in place
+                    return _shared_block(shared_p, h, cfg, positions,
+                                         shared_kv, cache_index, a_fmt,
+                                         layer=inv)
                 if shared_kv is not None:
                     kv_i = jax.tree.map(lambda c: c[inv], shared_kv)
                 else:

@@ -79,8 +79,11 @@ def mla_attention(
     kv_cache=None,
     cache_index=None,
     a_fmt: Optional[str] = None,
+    layer=None,
 ):
-    """Returns (out, new_cache_or_None)."""
+    """Returns (out, new_cache_or_None). Paged (``cache_index`` a
+    PagedState): ``kv_cache`` is the whole stacked latent page pool and
+    ``layer`` the layer this call reads and writes."""
     m, h = cfg.mla, cfg.n_heads
     b, s, _ = x.shape
     scale_dim = m.qk_nope_dim + m.qk_rope_dim
@@ -121,12 +124,12 @@ def mla_attention(
             kr_dec = jnp.swapaxes(k_rope[:, :nd], 0, 1)
             with jax.named_scope("kv_write"):
                 cache1 = append_paged(
-                    kv_cache, {"ckv": ckv_dec, "krope": kr_dec}, dec)
+                    kv_cache, layer, {"ckv": ckv_dec, "krope": kr_dec}, dec)
                 new_cache = append_prefill_chunk(
-                    cache1, {"ckv": c_kv[:, nd:], "krope": k_rope[:, nd:]},
-                    pre)
+                    cache1, layer,
+                    {"ckv": c_kv[:, nd:], "krope": k_rope[:, nd:]}, pre)
             sc = s - nd
-            hist, hist_len = gather_history(new_cache, pre, sc)
+            hist, hist_len = gather_history(new_cache, layer, pre, sc)
             start = pre.lengths[0]
             ckv = c_kv[:, nd:].astype(jnp.bfloat16)
             krope = k_rope[:, nd:].astype(jnp.bfloat16)
@@ -143,8 +146,8 @@ def mla_attention(
         elif s == 1 and cache_index.chunk_len is None:
             with jax.named_scope("kv_write"):
                 new_cache = append_paged(
-                    kv_cache, {"ckv": c_kv, "krope": k_rope}, cache_index
-                )
+                    kv_cache, layer, {"ckv": c_kv, "krope": k_rope},
+                    cache_index)
         else:
             # streaming prefill: write the page-aligned chunk in-graph, then
             # attend over the gathered table + the chunk's own exact latents
@@ -157,9 +160,9 @@ def mla_attention(
             assert b == 1, "streaming paged prefill is row-wise (batch 1)"
             with jax.named_scope("kv_write"):
                 new_cache = append_prefill_chunk(
-                    kv_cache, {"ckv": c_kv, "krope": k_rope}, cache_index
-                )
-            hist, hist_len = gather_history(new_cache, cache_index, s)
+                    kv_cache, layer, {"ckv": c_kv, "krope": k_rope},
+                    cache_index)
+            hist, hist_len = gather_history(new_cache, layer, cache_index, s)
             start = cache_index.lengths[0]
             ckv = c_kv.astype(jnp.bfloat16)
             krope = k_rope.astype(jnp.bfloat16)
@@ -215,7 +218,7 @@ def mla_attention(
             q_lat_d = shard_heads(jnp.swapaxes(q_lat[:, :nd], 0, 1))
             q_rope_d = shard_heads(jnp.swapaxes(q_rope[:, :nd], 0, 1))
             ctx_dec = ops.paged_mla_decode_attn(
-                q_lat_d[:, 0], q_rope_d[:, 0], new_cache,
+                q_lat_d[:, 0], q_rope_d[:, 0], new_cache, layer,
                 dec.page_table, dec.lengths + 1,
                 scale=1.0 / float(scale_dim) ** 0.5,
             )  # (nd, H, r)
@@ -246,7 +249,7 @@ def mla_attention(
             q_lat = shard_heads(q_lat)
             q_rope = shard_heads(q_rope)
             ctx_lat = ops.paged_mla_decode_attn(
-                q_lat[:, 0], q_rope[:, 0], new_cache,
+                q_lat[:, 0], q_rope[:, 0], new_cache, layer,
                 cache_index.page_table, cache_index.lengths + 1,
                 scale=1.0 / float(scale_dim) ** 0.5,
             )[:, None].astype(x.dtype)  # (B, 1, H, r)

@@ -142,8 +142,11 @@ def block_apply(
     cache_index=None,
     a_fmt=None,
     enc_out=None,
+    layer=None,
 ):
-    """Returns (x, new_cache, aux)."""
+    """Returns (x, new_cache, aux). ``layer``: for paged GQA/MLA blocks,
+    ``cache`` is the segment's whole stacked pools and ``layer`` the index
+    this block reads and writes (see ``_segment_scan``)."""
     aux = jnp.zeros((), jnp.float32)
     nk = cfg.norm_kind
     pm = p["mixer"]
@@ -155,7 +158,7 @@ def block_apply(
             h, new_kv = attention(
                 pm["attn"], norm(pm["ln"], x, nk, cfg.norm_eps), cfg,
                 positions, kv_cache=None if cache is None else cache["kv"],
-                cache_index=cache_index, a_fmt=a_fmt,
+                cache_index=cache_index, a_fmt=a_fmt, layer=layer,
             )
         x = x + h
         if cache is not None:
@@ -169,7 +172,8 @@ def block_apply(
                     h = paged_cross_attention(
                         pm["cross"],
                         norm(pm["ln_cross"], x, nk, cfg.norm_eps), cfg,
-                        positions, cache["cross"], cache_index, a_fmt=a_fmt,
+                        positions, cache["cross"], layer, cache_index,
+                        a_fmt=a_fmt,
                     )
             else:
                 is_decode = cache is not None and x.shape[1] == 1
@@ -193,7 +197,7 @@ def block_apply(
             h, new_kv = mla_attention(
                 pm["attn"], norm(pm["ln"], x, nk, cfg.norm_eps), cfg,
                 positions, kv_cache=None if cache is None else cache["kv"],
-                cache_index=cache_index, a_fmt=a_fmt,
+                cache_index=cache_index, a_fmt=a_fmt, layer=layer,
             )
         x = x + h
         if cache is not None:
@@ -340,27 +344,53 @@ def _embed_tokens(params, cfg, tokens, embeds_prefix=None):
 
 
 def _segment_scan(p_stack, x, cfg, seg, positions, caches, cache_index, a_fmt, enc_out, remat):
-    """Scan one segment's stacked params (and stacked caches) over depth."""
+    """Scan one segment's stacked params (and stacked caches) over depth.
 
-    def body(carry, layer_in):
-        h, aux_acc = carry
-        p_layer, cache_layer = layer_in
-        h = shard_residual(h)  # sequence-parallel residual (no-op off-mesh)
-        h, new_cache, aux = block_apply(
-            p_layer, h, cfg, seg, positions, cache_layer, cache_index, a_fmt, enc_out
-        )
-        return (h, aux_acc + aux), new_cache
+    Paged GQA/MLA segments (``cache_index`` a PagedState) carry their page
+    pools whole, ``(L, P+1, ...)``, through the scan and hand each layer
+    the stacked pools plus its layer index: the appends update the touched
+    pages in place and the attention reads its layer straight out of the
+    stack. Passed as ``xs``/``ys`` instead, every layer's whole store would
+    be copied out of the pool and written back into a fresh one on every
+    step. Every other cache (contiguous decode caches, recurrent state
+    slabs) rides ``xs``/``ys`` as one slice per layer."""
+    carried = (caches is not None and isinstance(cache_index, PagedState)
+               and seg.mixer in ("gqa", "mla"))
+
+    if carried:
+        def body(carry, layer_in):
+            h, aux_acc, pools = carry
+            p_layer, layer = layer_in
+            h = shard_residual(h)  # sequence-parallel residual (no-op off-mesh)
+            h, pools, aux = block_apply(
+                p_layer, h, cfg, seg, positions, pools, cache_index, a_fmt,
+                enc_out, layer=layer)
+            return (h, aux_acc + aux, pools), None
+
+        init = (x, jnp.zeros((), jnp.float32), caches)
+        xs = (p_stack, jnp.arange(seg.count))
+    else:
+        def body(carry, layer_in):
+            h, aux_acc, _ = carry
+            p_layer, cache_layer = layer_in
+            h = shard_residual(h)  # sequence-parallel residual (no-op off-mesh)
+            h, new_cache, aux = block_apply(
+                p_layer, h, cfg, seg, positions, cache_layer, cache_index,
+                a_fmt, enc_out)
+            return (h, aux_acc + aux, None), new_cache
+
+        init = (x, jnp.zeros((), jnp.float32), None)
+        xs = (p_stack, caches)
 
     if remat:
         body = jax.checkpoint(body)
 
     # device ops under "layers" and in none of the body's scopes are the
-    # scan's own traffic: each layer's weight and cache slices coming in,
-    # the caches going back out
+    # scan's own traffic: each layer's weight slices (and, off the paged
+    # path, cache slices) coming in, per-layer caches going back out
     with jax.named_scope("layers"):
-        (x, aux), new_caches = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), (p_stack, caches))
-    return x, aux, new_caches
+        (x, aux, pools), new_caches = jax.lax.scan(body, init, xs)
+    return x, aux, (pools if carried else new_caches)
 
 
 def lm_forward(

@@ -10,8 +10,7 @@ kernel applies scales as an exponent add (kernels.common.decode_fp8) and
 multiplies by s_max once per page. Halving KV bytes doubles the slot pool
 for the same HBM.
 
-Layout (one pool dict per model segment, leading dim = stacked layers so it
-rides the per-segment lax.scan exactly like the old monolithic caches):
+Layout (one pool dict per model segment, leading dim = stacked layers):
 
   GQA:  k/v        (L, P+1, page, KV, hd)  uint8 codes (fp8) | bf16 values
         k/v_smax   (L, P+1)                f32   per-page full-precision S_max
@@ -23,6 +22,15 @@ Page ids are *global across layers*: page p of every layer belongs to the
 same logical page, so one host-side free list serves the whole stack. The
 last page id (index P) is a reserved null page — in-graph appends from
 inactive batch rows are redirected there instead of corrupting a live page.
+
+The ``(pool, layer)`` contract: the in-graph readers and writers below
+(``append_paged``, ``append_prefill_chunk``, ``write_cross_pages``,
+``gather_pages``, ``gather_history``, and the decode kernels behind
+``kernels.ops``) take the whole stacked pool plus a layer index, and
+address ``store[layer, pid]``. The pool rides the per-segment lax.scan's
+carry, so a step moves only the pages it touches: slicing a layer out of
+the pool and stacking it back would copy every layer's whole store twice
+per step.
 
 Mixed precision (``CachePolicy(frozen_fmt="fp4_e2m1")``): the pool grows a
 dedicated *frozen region* — half-width packed FP4 E2M1 stores (``k_fz`` et
@@ -233,15 +241,10 @@ def frozen_format(pool: Dict) -> Optional[PageFormat]:
 
 
 def n_frozen_pages(pool: Dict) -> int:
-    """Frozen-region page count (0 when homogeneous). Works on full pools
-    (leading layer dim) and per-layer slices alike — the value-leaf rank
-    tells them apart (GQA k_fz: 5-D full / 4-D per-layer; MLA ckv_fz:
-    4-D / 3-D)."""
-    full_rank = 5 if "k" in pool else 4
+    """Frozen-region page count of a stacked pool (0 when homogeneous)."""
     for name, leaf in pool.items():
         if name.endswith("_fz"):
-            axis = 1 if leaf.ndim == full_rank else 0
-            return leaf.shape[axis] - 1
+            return leaf.shape[1] - 1
     return 0
 
 
@@ -276,8 +279,7 @@ def _finish_pool(pool: Dict, fmt: PageFormat, frozen_fmt, n_frozen: int,
     if fmt.packed:
         # zero-size marker: the jit-safe static channel that tells readers
         # this uint8 store is packed FP4, not FP8 (see pool_format). Leading
-        # dim matches the stacked layers so the leaf rides the per-segment
-        # lax.scan (sliced to a per-layer (0,) that costs nothing).
+        # dim matches the stacked layers, like every other pool leaf.
         n_layers = pool[pool_keys(pool)[0]].shape[0]
         pool["_fp4"] = jnp.zeros((n_layers, 0), jnp.uint8)
     frozen_fmt = page_format(frozen_fmt) if frozen_fmt is not None else None
@@ -483,27 +485,29 @@ def splice_prefill(pool: Dict, prefill_cache: Dict, page_ids: np.ndarray,
 # ---------------------------------------------------------------------------
 # Decode append (in-graph: runs inside the jitted decode step, per layer)
 # ---------------------------------------------------------------------------
-def append_paged(pool_layer: Dict, new_vals: Dict, state: PagedState) -> Dict:
+def append_paged(pool: Dict, layer, new_vals: Dict, state: PagedState) -> Dict:
     """Write one new token per batch row at its row's true position.
 
-    pool_layer: one layer's slice of a pool (no leading L dim).
+    pool: a whole stacked pool (leading L dim); ``layer``: the (traced)
+    layer index to write. Only the touched pages of that layer are read and
+    written (``store[layer, pid]``); the returned pool is the same stack.
     new_vals: {"k": (B, 1, KV, hd), "v": ...} or {"ckv": (B, 1, r), ...}.
     Rows with lengths == 0 (empty slots) are redirected to the null page.
     """
-    pf = pool_format(pool_layer)
+    pf = pool_format(pool)
     # the no-write-to-FP4 invariant, enforced at trace time: a packed page
     # is frozen by definition (transcoded exactly once, read-only after),
     # and requantizing through the 3-bit E2M1 grid would compound error
     assert not pf.packed, \
         "decode append must never target packed FP4 pages (frozen pages are read-only)"
     b = state.lengths.shape[0]
-    out = dict(pool_layer)
+    out = dict(pool)
     rows = jnp.arange(b)
-    for name in pool_keys(pool_layer):
-        store = pool_layer[name]
-        has_heads = store.ndim == 4  # (P+1, page, KV, hd) vs (P+1, page, dim)
-        page = store.shape[1]
-        null = store.shape[0] - 1
+    for name in pool_keys(pool):
+        store = pool[name]
+        has_heads = store.ndim == 5  # (L, P+1, page, KV, hd) vs (L, P+1, page, dim)
+        page = store.shape[2]
+        null = store.shape[1] - 1
         slot = state.lengths // page
         off = state.lengths % page
         pid = jnp.take_along_axis(state.page_table, slot[:, None], axis=1)[:, 0]
@@ -516,11 +520,11 @@ def append_paged(pool_layer: Dict, new_vals: Dict, state: PagedState) -> Dict:
         new = _with_head_axis(new, has_heads)  # (B, KV|1, hd)
         if not pf.quantized:
             val = new if has_heads else new[:, 0]
-            out[name] = store.at[pid, off].set(val.astype(store.dtype))
+            out[name] = store.at[layer, pid, off].set(val.astype(store.dtype))
             continue
-        codes = _with_head_axis(store[pid], has_heads)  # (B, page, KV|1, hd)
-        smax = pool_layer[name + "_smax"][pid]  # (B,)
-        shifts = pool_layer[name + "_shift"][pid]  # (B, KV|1)
+        codes = _with_head_axis(store[layer, pid], has_heads)  # (B, page, KV|1, hd)
+        smax = pool[name + "_smax"][layer, pid]  # (B,)
+        shifts = pool[name + "_shift"][layer, pid]  # (B, KV|1)
         vals = dequantize_pages(codes, smax, shifts)
         vals = vals.at[rows, off].set(new)
         # zero page slots past this row's position: a recycled page may
@@ -533,17 +537,18 @@ def append_paged(pool_layer: Dict, new_vals: Dict, state: PagedState) -> Dict:
         ncodes, nsmax, nshift = quantize_pages(vals)
         if not has_heads:
             ncodes = ncodes[..., 0, :]
-        out[name] = store.at[pid].set(ncodes)
-        out[name + "_smax"] = pool_layer[name + "_smax"].at[pid].set(nsmax)
-        out[name + "_shift"] = pool_layer[name + "_shift"].at[pid].set(nshift)
+        out[name] = store.at[layer, pid].set(ncodes)
+        out[name + "_smax"] = pool[name + "_smax"].at[layer, pid].set(nsmax)
+        out[name + "_shift"] = pool[name + "_shift"].at[layer, pid].set(nshift)
     return out
 
 
-def append_prefill_chunk(pool_layer: Dict, new_vals: Dict,
+def append_prefill_chunk(pool: Dict, layer, new_vals: Dict,
                          state: PagedState) -> Dict:
     """Write one page-aligned chunk of a (batch-1) streaming prefill.
 
-    pool_layer: one layer's slice of a pool (no leading L dim).
+    pool: a whole stacked pool (leading L dim); ``layer``: the layer index
+    to write (only the chunk's pages of that layer move).
     new_vals: {"k": (1, S, KV, hd), ...} or {"ckv": (1, S, r), ...} — S
     prompt tokens starting at position ``state.lengths[0]``, which must be
     a page-size multiple (the engine feeds page-aligned chunks; only the
@@ -561,13 +566,13 @@ def append_prefill_chunk(pool_layer: Dict, new_vals: Dict,
     so they cannot leak into the page amax (and so the scales). Pages the
     pad region overhangs must point at the null page in ``page_table``.
     """
-    pf = pool_format(pool_layer)
-    out = dict(pool_layer)
+    pf = pool_format(pool)
+    out = dict(pool)
     start = state.lengths[0]
-    for name in pool_keys(pool_layer):
-        store = pool_layer[name]
-        has_heads = store.ndim == 4  # (P+1, page, KV, hd) vs (P+1, page, dim)
-        page = store.shape[1]
+    for name in pool_keys(pool):
+        store = pool[name]
+        has_heads = store.ndim == 5  # (L, P+1, page, KV, hd) vs (L, P+1, page, dim)
+        page = store.shape[2]
         new = new_vals[name].astype(jnp.float32)[0]  # (S, KV, hd) | (S, dim)
         s = new.shape[0]
         if state.chunk_len is not None:  # zero the pad tail of a bucketed chunk
@@ -592,26 +597,27 @@ def append_prefill_chunk(pool_layer: Dict, new_vals: Dict,
         # a mixed pool any frozen id here would be a bug — clamp to the
         # null page so it cannot index out of bounds in-graph (the engine's
         # assert_unfrozen catches the bug host-side)
-        pid = jnp.minimum(pid, store.shape[0] - 1)
+        pid = jnp.minimum(pid, store.shape[1] - 1)
         if pf.quantized:
             codes, smax, shifts = quantize_pages(vals, pf)
             if not has_heads:
                 codes = codes[..., 0, :]
-            out[name] = store.at[pid].set(codes)
-            out[name + "_smax"] = pool_layer[name + "_smax"].at[pid].set(smax)
-            out[name + "_shift"] = pool_layer[name + "_shift"].at[pid].set(shifts)
+            out[name] = store.at[layer, pid].set(codes)
+            out[name + "_smax"] = pool[name + "_smax"].at[layer, pid].set(smax)
+            out[name + "_shift"] = pool[name + "_shift"].at[layer, pid].set(shifts)
         else:
             stv = vals if has_heads else vals[..., 0, :]
-            out[name] = store.at[pid].set(stv.astype(store.dtype))
+            out[name] = store.at[layer, pid].set(stv.astype(store.dtype))
     return out
 
 
-def write_cross_pages(pool_layer: Dict, new_vals: Dict,
+def write_cross_pages(pool: Dict, layer, new_vals: Dict,
                       cross_table: jnp.ndarray) -> Dict:
     """Write one layer's encoder-derived cross K/V into its (write-once)
     cross pages, in one shot at encode time.
 
-    pool_layer: one layer's slice of an ``init_cross_pool`` pool.
+    pool: a whole stacked ``init_cross_pool`` pool; ``layer``: the decoder
+    layer whose pages are written.
     new_vals: {"k": (1, T_enc, KV, hd), "v": ...} — the full encoder
     sequence. cross_table: (1, cross_pp) page ids covering T_enc (tail
     entries past ceil(T_enc / page) are never written).
@@ -620,7 +626,7 @@ def write_cross_pages(pool_layer: Dict, new_vals: Dict,
     so the per-(page, head) M2 scales computed here are final.
     """
     state = PagedState(cross_table, jnp.zeros((1,), jnp.int32))
-    return append_prefill_chunk(pool_layer, new_vals, state)
+    return append_prefill_chunk(pool, layer, new_vals, state)
 
 
 # ---------------------------------------------------------------------------
@@ -647,28 +653,29 @@ def scatter_slabs(pool_layer, slab_ids, new_rows):
         pool_layer, new_rows)
 
 
-def gather_pages(pool_layer: Dict, name: str, state: PagedState):
+def gather_pages(pool: Dict, layer, name: str, state: PagedState):
     """Dequantized gather for the jnp paths: (B, PP * page, KV, hd) f32 for
-    GQA leaves, (B, PP * page, dim) for MLA leaves.
+    GQA leaves, (B, PP * page, dim) for MLA leaves, read from layer
+    ``layer`` of the stacked ``pool`` (only the table's pages move).
 
     Mixed-format pools: table entries ``>= P+1`` are frozen-region logical
     ids (``base + fidx``). Both regions are gathered with clamped indices
     (frozen ids clamp to the null page in the active store and vice versa)
     and the per-page format select is a ``where`` on the id class — the same
     dataflow the Pallas kernels implement with a prefetched frozen mask."""
-    store = pool_layer[name]
-    pf = pool_format(pool_layer)
-    fz = frozen_format(pool_layer)
-    has_heads = store.ndim == 4
-    page = store.shape[1]
+    store = pool[name]
+    pf = pool_format(pool)
+    fz = frozen_format(pool)
+    has_heads = store.ndim == 5
+    page = store.shape[2]
     b, pp = state.page_table.shape
     pt = state.page_table
-    base = store.shape[0]  # P+1: first frozen logical id
+    base = store.shape[1]  # P+1: first frozen logical id
     apt = jnp.minimum(pt, base - 1) if fz is not None else pt
-    pages = store[apt]  # (B, PP, page, ...)
+    pages = store[layer, apt]  # (B, PP, page, ...)
     if pf.quantized:
-        smax = pool_layer[name + "_smax"][apt]  # (B, PP)
-        shifts = pool_layer[name + "_shift"][apt]  # (B, PP, KV|1)
+        smax = pool[name + "_smax"][layer, apt]  # (B, PP)
+        shifts = pool[name + "_shift"][layer, apt]  # (B, PP, KV|1)
         d = store.shape[-1] * (2 if pf.packed else 1)
         vals = dequantize_pages(_with_head_axis(pages, has_heads), smax,
                                 shifts, pf, d=d)
@@ -677,11 +684,11 @@ def gather_pages(pool_layer: Dict, name: str, state: PagedState):
     else:
         vals = pages.astype(jnp.float32)
     if fz is not None:
-        fstore = pool_layer[name + "_fz"]
-        fpt = jnp.clip(pt - base, 0, fstore.shape[0] - 1)
-        fsmax = pool_layer[name + "_fz_smax"][fpt]
-        fshift = pool_layer[name + "_fz_shift"][fpt]
-        fvals = dequantize_pages(_with_head_axis(fstore[fpt], has_heads),
+        fstore = pool[name + "_fz"]
+        fpt = jnp.clip(pt - base, 0, fstore.shape[1] - 1)
+        fsmax = pool[name + "_fz_smax"][layer, fpt]
+        fshift = pool[name + "_fz_shift"][layer, fpt]
+        fvals = dequantize_pages(_with_head_axis(fstore[layer, fpt], has_heads),
                                  fsmax, fshift, fz, d=store.shape[-1])
         if not has_heads:
             fvals = fvals[..., 0, :]
@@ -690,9 +697,10 @@ def gather_pages(pool_layer: Dict, name: str, state: PagedState):
     return vals.reshape(b, pp * page, *vals.shape[3:])
 
 
-def gather_history(pool_layer: Dict, state: PagedState, chunk_len: int):
+def gather_history(pool: Dict, layer, state: PagedState, chunk_len: int):
     """History gather for a streaming-prefill chunk (the shared page math
-    for the GQA and MLA model glue — keep it in one place).
+    for the GQA and MLA model glue — keep it in one place), from layer
+    ``layer`` of the stacked ``pool``.
 
     The chunk starts page-aligned at ``state.lengths[0]``, so every token
     of the gather below that (dynamic) position is fully-packed history:
@@ -705,12 +713,11 @@ def gather_history(pool_layer: Dict, state: PagedState, chunk_len: int):
     table is no wider than the chunk itself (prompt fits one chunk,
     nothing could be history).
     """
-    first = pool_layer[pool_keys(pool_layer)[0]]
-    page = first.shape[1]
+    page = pool[pool_keys(pool)[0]].shape[2]
     if state.page_table.shape[1] <= -(-chunk_len // page):
         return {}, 0
-    return ({name: gather_pages(pool_layer, name, state)
-             for name in pool_keys(pool_layer)},
+    return ({name: gather_pages(pool, layer, name, state)
+             for name in pool_keys(pool)},
             state.page_table.shape[1] * page)
 
 

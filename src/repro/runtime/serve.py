@@ -134,6 +134,11 @@ def _decode_step(params, caches, tokens, cache_index, poison, samp,
     never drift step to step, so the fixed-trace property holds on a
     mesh exactly as it does on one device).
 
+    The layer scan carries the paged pool whole and updates it in place;
+    the step does not donate ``caches``, so XLA copies the pool once at the
+    step's entry into the buffer it returns, and a reference taken before
+    the step still reads the pool as it was.
+
     ``cfg`` is a frozen (hashable) ArchConfig, so the compiled program
     cache is shared across Server instances — a restarted or side-by-side
     server reuses every prefill-chunk and decode executable instead of

@@ -144,8 +144,7 @@ def _mixed_gqa_layer(rng, kv, hd, page, pp, lens, freeze):
             pool = kvc.transcode_page(pool, int(ids[i]), fidx)
             pt[r, i] = base + fidx
             fidx += 1
-    layer = {k: v[0] for k, v in pool.items()}
-    return layer, pt, kc[:, 0, 0], vc[:, 0, 0]
+    return pool, pt, kc[:, 0, 0], vc[:, 0, 0]
 
 
 def _attn_exact(q, k, v, kv_len, g):
@@ -163,9 +162,9 @@ class TestTranscode:
     def test_roundtrip_within_fp4_grid_error(self):
         rng = np.random.default_rng(0)
         lens = np.array([24, 9], np.int32)
-        layer, pt, kc, _ = _mixed_gqa_layer(rng, 2, 16, 8, 3, lens, freeze=2)
+        pool, pt, kc, _ = _mixed_gqa_layer(rng, 2, 16, 8, 3, lens, freeze=2)
         state = kvc.PagedState(jnp.asarray(pt), jnp.asarray(lens))
-        got = np.asarray(kvc.gather_pages(layer, "k", state))
+        got = np.asarray(kvc.gather_pages(pool, 0, "k", state))
         for r, n in enumerate(lens):
             ref = kc[r, :n]
             err = np.abs(got[r, :n] - ref).max() / np.abs(ref).max()
@@ -181,10 +180,10 @@ class TestTranscode:
     def test_odd_head_dim_packs_with_pad_nibble(self):
         rng = np.random.default_rng(1)
         lens = np.array([10], np.int32)
-        layer, pt, kc, _ = _mixed_gqa_layer(rng, 2, 9, 8, 2, lens, freeze=1)
-        assert layer["k_fz"].shape[-1] == 5  # ceil(9 / 2)
+        pool, pt, kc, _ = _mixed_gqa_layer(rng, 2, 9, 8, 2, lens, freeze=1)
+        assert pool["k_fz"].shape[-1] == 5  # ceil(9 / 2)
         state = kvc.PagedState(jnp.asarray(pt), jnp.asarray(lens))
-        got = np.asarray(kvc.gather_pages(layer, "k", state))[0, :10]
+        got = np.asarray(kvc.gather_pages(pool, 0, "k", state))[0, :10]
         err = np.abs(got - kc[0, :10]).max() / np.abs(kc[0, :10]).max()
         assert err < FP4_TOL, err
 
@@ -204,12 +203,11 @@ class TestTranscode:
 class TestNoWritePathTargetsFP4:
     def test_append_asserts_on_packed_pages(self):
         pool = kvc.init_gqa_pool(1, 4, 8, 2, 16, "fp4_e2m1")
-        layer = {k: v[0] for k, v in pool.items()}
         state = kvc.PagedState(jnp.asarray([[0, 1]], jnp.int32),
                                jnp.asarray([3], jnp.int32))
         new = {"k": jnp.ones((1, 1, 2, 16)), "v": jnp.ones((1, 1, 2, 16))}
         with pytest.raises(AssertionError, match="packed FP4"):
-            kvc.append_paged(layer, new, state)
+            kvc.append_paged(pool, 0, new, state)
 
     def test_mixed_pool_requires_fp8_active(self):
         with pytest.raises(ValueError, match="fp4_e2m1"):
@@ -244,16 +242,16 @@ class TestFP4DecodeParity:
         h = kv * g
         lens = np.array([page * pp - 3, max(1, page // 2)], np.int32)
         q = jnp.asarray(rng.normal(size=(2, h, hd)).astype(np.float32))
-        layer, pt, kc, vc = _mixed_gqa_layer(rng, kv, hd, page, pp, lens,
+        pool, pt, kc, vc = _mixed_gqa_layer(rng, kv, hd, page, pp, lens,
                                              freeze=pp - 1)
         assert (pt >= pt.shape[0] * pp + 1).any()  # frozen ids in play
         prev = ops.get_backend()
         try:
             ops.set_backend("ref")
-            o_ref = ops.paged_decode_attn(q, layer, jnp.asarray(pt),
+            o_ref = ops.paged_decode_attn(q, pool, 0, jnp.asarray(pt),
                                           jnp.asarray(lens))
             ops.set_backend("pallas_interpret")
-            o_pal = ops.paged_decode_attn(q, layer, jnp.asarray(pt),
+            o_pal = ops.paged_decode_attn(q, pool, 0, jnp.asarray(pt),
                                           jnp.asarray(lens))
         finally:
             ops.set_backend(prev)
@@ -291,7 +289,6 @@ class TestFP4DecodeParity:
                 pool = kvc.transcode_page(pool, int(ids[i]), fidx)
                 pt[row, i] = base + fidx
                 fidx += 1
-        layer = {k: v[0] for k, v in pool.items()}
         ql = jnp.asarray(rng.normal(size=(b, h, r)).astype(np.float32))
         qr = jnp.asarray(rng.normal(size=(b, h, dr)).astype(np.float32))
         scale = 1.0 / float(r + dr) ** 0.5
@@ -299,10 +296,10 @@ class TestFP4DecodeParity:
         try:
             ops.set_backend("ref")
             o_ref = ops.paged_mla_decode_attn(
-                ql, qr, layer, jnp.asarray(pt), jnp.asarray(lens), scale)
+                ql, qr, pool, 0, jnp.asarray(pt), jnp.asarray(lens), scale)
             ops.set_backend("pallas_interpret")
             o_pal = ops.paged_mla_decode_attn(
-                ql, qr, layer, jnp.asarray(pt), jnp.asarray(lens), scale)
+                ql, qr, pool, 0, jnp.asarray(pt), jnp.asarray(lens), scale)
         finally:
             ops.set_backend(prev)
         np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_ref),

@@ -46,17 +46,16 @@ def _filled_pool(rng, kv, hd, page, pp, lens, fmt):
         pool = kvc.splice_prefill(
             pool, {"k": jnp.asarray(kc[r]), "v": jnp.asarray(vc[r])}, ids,
             int(lens[r]))
-    layer = {k: v[0] for k, v in pool.items()}
-    return layer, pt, kc[:, 0, 0], vc[:, 0, 0]
+    return pool, pt, kc[:, 0, 0], vc[:, 0, 0]
 
 
 class TestPagedPool:
     def test_splice_gather_roundtrip_fp8(self):
         rng = np.random.default_rng(0)
         lens = np.array([13, 5], np.int32)
-        layer, pt, kc, _ = _filled_pool(rng, 2, 16, 8, 3, lens, "fp8_e4m3")
+        pool, pt, kc, _ = _filled_pool(rng, 2, 16, 8, 3, lens, "fp8_e4m3")
         state = kvc.PagedState(jnp.asarray(pt), jnp.asarray(lens))
-        got = np.asarray(kvc.gather_pages(layer, "k", state))
+        got = np.asarray(kvc.gather_pages(pool, 0, "k", state))
         for r, n in enumerate(lens):
             ref = kc[r, :n]
             err = np.abs(got[r, :n] - ref).max() / np.abs(ref).max()
@@ -76,15 +75,14 @@ class TestPagedPool:
             pool, {"k": jnp.asarray(stream[None, None, None, :1]),
                    "v": jnp.asarray(stream[None, None, None, :1])},
             np.array([0]), 1)
-        layer = {k: v[0] for k, v in pool.items()}
         pt = jnp.asarray([[0, 1, 2]], jnp.int32)
         app = jax.jit(kvc.append_paged)
         for t in range(1, seq):
             state = kvc.PagedState(pt, jnp.asarray([t], jnp.int32))
             tok = jnp.asarray(stream[t][None, None])
-            layer = app(layer, {"k": tok, "v": tok}, state)
+            pool = app(pool, 0, {"k": tok, "v": tok}, state)
         state = kvc.PagedState(pt, jnp.asarray([seq], jnp.int32))
-        got = np.asarray(kvc.gather_pages(layer, "k", state))[0, :seq]
+        got = np.asarray(kvc.gather_pages(pool, 0, "k", state))[0, :seq]
         err = np.abs(got - stream).max() / np.abs(stream).max()
         # appends requantize the touched page; with unchanged scales the
         # decode->encode is exact, so error stays at one-quantization level
@@ -94,12 +92,12 @@ class TestPagedPool:
         """Inactive rows (lengths == 0) must not corrupt live pages."""
         rng = np.random.default_rng(2)
         lens = np.array([9, 0], np.int32)
-        layer, pt, kc, _ = _filled_pool(rng, 2, 8, 8, 2, lens, "fp8_e4m3")
+        pool, pt, kc, _ = _filled_pool(rng, 2, 8, 8, 2, lens, "fp8_e4m3")
         state = kvc.PagedState(jnp.asarray(pt), jnp.asarray(lens))
-        before = np.asarray(kvc.gather_pages(layer, "k", state))[0, :9]
+        before = np.asarray(kvc.gather_pages(pool, 0, "k", state))[0, :9]
         new = {"k": jnp.ones((2, 1, 2, 8)), "v": jnp.ones((2, 1, 2, 8))}
-        layer = jax.jit(kvc.append_paged)(layer, new, state)
-        after = np.asarray(kvc.gather_pages(layer, "k", state))[0, :9]
+        pool = jax.jit(kvc.append_paged)(pool, 0, new, state)
+        after = np.asarray(kvc.gather_pages(pool, 0, "k", state))[0, :9]
         np.testing.assert_allclose(after, before, rtol=1e-6, atol=1e-6)
 
     def test_splice_overhangs_prefill_cache(self):
@@ -116,8 +114,7 @@ class TestPagedPool:
         pool = kvc.splice_prefill(pool, cache, np.array([0, 1, 2]), n)
         state = kvc.PagedState(jnp.asarray([[0, 1, 2]], jnp.int32),
                                jnp.asarray([n], jnp.int32))
-        layer = {k: v[0] for k, v in pool.items()}
-        got = np.asarray(kvc.gather_pages(layer, "k", state))[0]
+        got = np.asarray(kvc.gather_pages(pool, 0, "k", state))[0]
         ref = np.asarray(cache["k"][0, 0, :n])
         assert np.abs(got[:n] - ref).max() / np.abs(ref).max() < 0.07
         np.testing.assert_array_equal(got[n:], 0)
@@ -149,12 +146,12 @@ class TestPagedDecodeAttn:
         try:
             outs = {}
             for fmt in ("fp8_e4m3", None):
-                layer, pt, kc, vc = _filled_pool(rng, kv, hd, page, pp, lens, fmt)
+                pool, pt, kc, vc = _filled_pool(rng, kv, hd, page, pp, lens, fmt)
                 ops.set_backend("ref")
-                o_ref = ops.paged_decode_attn(q, layer, jnp.asarray(pt),
+                o_ref = ops.paged_decode_attn(q, pool, 0, jnp.asarray(pt),
                                               jnp.asarray(lens))
                 ops.set_backend("pallas_interpret")
-                o_pal = ops.paged_decode_attn(q, layer, jnp.asarray(pt),
+                o_pal = ops.paged_decode_attn(q, pool, 0, jnp.asarray(pt),
                                               jnp.asarray(lens))
                 np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_ref),
                                            rtol=2e-5, atol=2e-5)
@@ -178,14 +175,14 @@ class TestPagedDecodeAttn:
         window = 6
         lens = np.array([20, 4], np.int32)  # row 1 shorter than the window
         q = jnp.asarray(rng.normal(size=(2, kv * g, hd)).astype(np.float32))
-        layer, pt, kc, vc = _filled_pool(rng, kv, hd, page, pp, lens, None)
+        pool, pt, kc, vc = _filled_pool(rng, kv, hd, page, pp, lens, None)
         prev = ops.get_backend()
         try:
             ops.set_backend("ref")
-            o_ref = ops.paged_decode_attn(q, layer, jnp.asarray(pt),
+            o_ref = ops.paged_decode_attn(q, pool, 0, jnp.asarray(pt),
                                           jnp.asarray(lens), window=window)
             ops.set_backend("pallas_interpret")
-            o_pal = ops.paged_decode_attn(q, layer, jnp.asarray(pt),
+            o_pal = ops.paged_decode_attn(q, pool, 0, jnp.asarray(pt),
                                           jnp.asarray(lens), window=window)
         finally:
             ops.set_backend(prev)
@@ -277,8 +274,7 @@ def _filled_mla_pool(rng, r, dr, page, pp, lens, fmt):
         pool = kvc.splice_prefill(
             pool, {"ckv": jnp.asarray(ck[row]), "krope": jnp.asarray(kr[row])},
             ids, int(lens[row]))
-    layer = {k: v[0] for k, v in pool.items()}
-    return layer, pt, ck[:, 0, 0], kr[:, 0, 0]
+    return pool, pt, ck[:, 0, 0], kr[:, 0, 0]
 
 
 class TestPagedMLAKernel:
@@ -300,14 +296,14 @@ class TestPagedMLAKernel:
         prev = ops.get_backend()
         try:
             for fmt in ("fp8_e4m3", None):
-                layer, pt, ck, kr = _filled_mla_pool(rng, r, dr, page, pp,
+                pool, pt, ck, kr = _filled_mla_pool(rng, r, dr, page, pp,
                                                      lens, fmt)
                 ops.set_backend("ref")
                 o_ref = ops.paged_mla_decode_attn(
-                    ql, qr, layer, jnp.asarray(pt), jnp.asarray(lens), scale)
+                    ql, qr, pool, 0, jnp.asarray(pt), jnp.asarray(lens), scale)
                 ops.set_backend("pallas_interpret")
                 o_pal = ops.paged_mla_decode_attn(
-                    ql, qr, layer, jnp.asarray(pt), jnp.asarray(lens), scale)
+                    ql, qr, pool, 0, jnp.asarray(pt), jnp.asarray(lens), scale)
                 np.testing.assert_allclose(np.asarray(o_pal),
                                            np.asarray(o_ref),
                                            rtol=2e-5, atol=2e-5)

@@ -175,10 +175,8 @@ class TestStreamingPrefill:
             st = kvc.PagedState(jnp.asarray([[0, 1, 2]], jnp.int32),
                                 jnp.asarray([n], jnp.int32))
             for name in ("k", "v"):
-                a = kvc.gather_pages({k: v[0] for k, v in pool_ref.items()},
-                                     name, st)
-                b = kvc.gather_pages(
-                    {k: v[0] for k, v in pools[0]["kv"].items()}, name, st)
+                a = kvc.gather_pages(pool_ref, 0, name, st)
+                b = kvc.gather_pages(pools[0]["kv"], 0, name, st)
                 np.testing.assert_allclose(np.asarray(b)[0, :n],
                                            np.asarray(a)[0, :n],
                                            rtol=5e-2, atol=5e-2)
@@ -222,10 +220,8 @@ class TestStreamingPrefill:
             st = kvc.PagedState(jnp.asarray([[0, 1]], jnp.int32),
                                 jnp.asarray([n], jnp.int32))
             for name in ("ckv", "krope"):
-                a = kvc.gather_pages({k: v[0] for k, v in pool_ref.items()},
-                                     name, st)
-                b = kvc.gather_pages({k: v[0] for k, v in pools[0]["kv"].items()},
-                                     name, st)
+                a = kvc.gather_pages(pool_ref, 0, name, st)
+                b = kvc.gather_pages(pools[0]["kv"], 0, name, st)
                 np.testing.assert_allclose(np.asarray(b)[0, :n],
                                            np.asarray(a)[0, :n],
                                            rtol=8e-2, atol=8e-2)

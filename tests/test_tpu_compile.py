@@ -13,6 +13,7 @@ process may load the TPU library, so each test-runner worker must collect
 the same tests and only the worker given this file may load it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -32,9 +33,10 @@ POLICY = QuantPolicy(w_fmt="fp4_e2m1", a_fmt="fp8_e4m3", scale_mode="m2",
 PACKED = ("codes", "scale", "s_max", "shifts", "lorc_a", "lorc_b")
 D, FF = OLMO.d_model, OLMO.d_ff
 HD = OLMO.d_model // OLMO.n_heads
-# the served pool: 8 slots x 2048 tokens in pages of 32
+# the served pool: 8 slots x 2048 tokens in pages of 32, every layer stacked
 PAGE, PP, SLOTS = 32, 64, 8
 P = SLOTS * PP
+L = OLMO.n_layers
 
 
 @pytest.fixture(scope="module")
@@ -67,6 +69,25 @@ def _compile(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return compiled
+
+
+def _assert_reads_stack_in_place(compiled):
+    """The kernel reads its layer out of the stacked pool, and what it
+    needs laid out per layer is made from the layer's slice: no
+    instruction but a parameter or a bitcast of one holds a whole stacked
+    leaf with a trailing unit dim (the (KV, 1) shift blocks), and nothing
+    rewrites a whole stacked code store (active or frozen) into another
+    layout — a ``copy``, fusion or custom call of it. An
+    asynchronous ``copy-start``/``copy-done`` moves an argument into VMEM in
+    its own layout (XLA's prefetch of a program argument) and is allowed."""
+    rows = f"{P + 1}|{P // 4 + 1}"
+    for line in compiled.as_text().splitlines():
+        if re.search(rf"= \w+\[{L},{P + 1}(,\d+)*,1\]", line):
+            assert re.search(r" (parameter|bitcast)\(", line), line
+        if re.search(rf"= (u8|bf16)\[{L},({rows})(,\d+){{2,}}\]", line):
+            op = re.search(r"= \S+ ([\w-]+)\(", line).group(1)
+            assert op in ("parameter", "bitcast", "copy-start",
+                          "copy-done"), line
 
 
 def _packed(sharding, shape):
@@ -115,21 +136,24 @@ def test_gqa_paged_decode_compiles(one_chip, fmt, frozen):
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     code = jnp.uint8 if fmt else jnp.bfloat16
     args = [s((SLOTS, OLMO.n_heads, HD), jnp.float32),
-            s((P + 1, PAGE, kv, HD), code), s((P + 1, PAGE, kv, HD), code),
-            s((P + 1,), jnp.float32), s((P + 1, kv), jnp.int32),
-            s((P + 1,), jnp.float32), s((P + 1, kv), jnp.int32),
-            s((SLOTS, PP), jnp.int32), s((SLOTS,), jnp.int32)]
+            s((L, P + 1, PAGE, kv, HD), code),
+            s((L, P + 1, PAGE, kv, HD), code),
+            s((L, P + 1), jnp.float32), s((L, P + 1, kv), jnp.int32),
+            s((L, P + 1), jnp.float32), s((L, P + 1, kv), jnp.int32),
+            s((SLOTS, PP), jnp.int32), s((SLOTS,), jnp.int32),
+            s((), jnp.int32)]
     names = ()
     if frozen:
         f = P // 4
-        args += [s((f + 1, PAGE, kv, HD // 2), jnp.uint8),
-                 s((f + 1, PAGE, kv, HD // 2), jnp.uint8),
-                 s((f + 1,), jnp.float32), s((f + 1, kv), jnp.int32),
-                 s((f + 1,), jnp.float32), s((f + 1, kv), jnp.int32)]
+        args += [s((L, f + 1, PAGE, kv, HD // 2), jnp.uint8),
+                 s((L, f + 1, PAGE, kv, HD // 2), jnp.uint8),
+                 s((L, f + 1), jnp.float32), s((L, f + 1, kv), jnp.int32),
+                 s((L, f + 1), jnp.float32), s((L, f + 1, kv), jnp.int32)]
         names = ("k_fz", "v_fz", "k_fz_smax", "k_fz_shift", "v_fz_smax",
                  "v_fz_shift")
-    _compile(lambda *a: paged_decode_attn_pallas(
-        *a[:9], fmt=fmt, frozen=frozen, **dict(zip(names, a[9:]))), *args)
+    compiled = _compile(lambda *a: paged_decode_attn_pallas(
+        *a[:10], fmt=fmt, frozen=frozen, **dict(zip(names, a[10:]))), *args)
+    _assert_reads_stack_in_place(compiled)
 
 
 @pytest.mark.parametrize("frozen", [None, "fp4_e2m1"])
@@ -139,22 +163,25 @@ def test_mla_paged_decode_compiles(one_chip, frozen):
     h, r, dr = 16, 512, 64
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     args = [s((SLOTS, h, r), jnp.float32), s((SLOTS, h, dr), jnp.float32),
-            s((P + 1, PAGE, r), jnp.uint8), s((P + 1, PAGE, dr), jnp.uint8),
-            s((P + 1,), jnp.float32), s((P + 1, 1), jnp.int32),
-            s((P + 1,), jnp.float32), s((P + 1, 1), jnp.int32),
-            s((SLOTS, PP), jnp.int32), s((SLOTS,), jnp.int32)]
+            s((L, P + 1, PAGE, r), jnp.uint8),
+            s((L, P + 1, PAGE, dr), jnp.uint8),
+            s((L, P + 1), jnp.float32), s((L, P + 1, 1), jnp.int32),
+            s((L, P + 1), jnp.float32), s((L, P + 1, 1), jnp.int32),
+            s((SLOTS, PP), jnp.int32), s((SLOTS,), jnp.int32),
+            s((), jnp.int32)]
     names = ()
     if frozen:
         f = P // 4
-        args += [s((f + 1, PAGE, r // 2), jnp.uint8),
-                 s((f + 1, PAGE, dr // 2), jnp.uint8),
-                 s((f + 1,), jnp.float32), s((f + 1, 1), jnp.int32),
-                 s((f + 1,), jnp.float32), s((f + 1, 1), jnp.int32)]
+        args += [s((L, f + 1, PAGE, r // 2), jnp.uint8),
+                 s((L, f + 1, PAGE, dr // 2), jnp.uint8),
+                 s((L, f + 1), jnp.float32), s((L, f + 1, 1), jnp.int32),
+                 s((L, f + 1), jnp.float32), s((L, f + 1, 1), jnp.int32)]
         names = ("ckv_fz", "krope_fz", "ckv_fz_smax", "ckv_fz_shift",
                  "krope_fz_smax", "krope_fz_shift")
-    _compile(lambda *a: paged_mla_decode_attn_pallas(
-        *a[:10], 1.0 / (128 + dr) ** 0.5, fmt="fp8_e4m3", frozen=frozen,
-        bq=16, **dict(zip(names, a[10:]))), *args)
+    compiled = _compile(lambda *a: paged_mla_decode_attn_pallas(
+        *a[:11], 1.0 / (128 + dr) ** 0.5, fmt="fp8_e4m3", frozen=frozen,
+        bq=16, **dict(zip(names, a[11:]))), *args)
+    _assert_reads_stack_in_place(compiled)
 
 
 def test_fused_gemm_compiles_on_mesh(topo, one_chip, monkeypatch):
